@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from benchmarks import common as C
 from repro.core.algorithms import ALGORITHMS, predict_raw
 from repro.core.postprocess import aggregate_raw
@@ -139,7 +140,6 @@ def run_fused_mesh(dataset="higgs", trees=(500,), batch=256, iters=3,
     interpret-mode kernel, so treat multi-device CPU numbers as overhead
     records, not speedup claims.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from repro.core.forest import pad_trees
@@ -167,9 +167,9 @@ def run_fused_mesh(dataset="higgs", trees=(500,), batch=256, iters=3,
             def body(xl, fl):
                 return jax.lax.psum(fn(fl, xl), "model")
 
-            sm = jax.jit(shard_map(
+            sm = jax.jit(jax.shard_map(
                 body, mesh=mesh, in_specs=(P("data", None), P("model")),
-                out_specs=P("data"), check_rep=False))
+                out_specs=P("data"), check_vma=False))
             t_mesh = C.time_best(sm, x, fp, iters=iters)
         else:
             t_mesh = t_single
@@ -229,4 +229,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

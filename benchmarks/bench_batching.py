@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from benchmarks import common as C
 from repro.core.reuse import ModelReuseCache
 from repro.db.query import ForestQueryEngine
@@ -57,4 +58,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from benchmarks import common as C
 from repro.core.algorithms import predict_raw
 from repro.core.forest import (complete_from_nodes, hb_path_matrix,
@@ -94,4 +95,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

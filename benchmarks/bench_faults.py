@@ -31,6 +31,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from benchmarks import common as C
 from repro.core.reuse import ModelReuseCache
 from repro.db.faults import FAULT_SITES, FaultInjector, RetryPolicy
@@ -174,4 +175,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

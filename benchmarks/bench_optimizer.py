@@ -33,6 +33,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from benchmarks import common as C
 from repro.core.train import TrainConfig, train_forest
 from repro.db.query import ForestQueryEngine
@@ -238,4 +239,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

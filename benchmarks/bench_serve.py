@@ -49,6 +49,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from benchmarks import common as C
 from repro.core.reuse import ModelReuseCache
 from repro.db.query import ForestQueryEngine
@@ -294,4 +295,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

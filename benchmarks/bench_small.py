@@ -11,6 +11,7 @@ import tempfile
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from benchmarks import common as C
 from repro.db import loader as ld
 from repro.db.query import ForestQueryEngine
@@ -64,4 +65,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

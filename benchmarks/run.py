@@ -12,6 +12,7 @@ import argparse
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
 from benchmarks import common as C
 
 
@@ -217,4 +218,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

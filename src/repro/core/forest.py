@@ -318,8 +318,7 @@ def used_feature_counts(forest: Forest) -> np.ndarray:
     """[T] number of DISTINCT features each tree really tests.
 
     Pass-through completion nodes (threshold +inf) don't count: their
-    predicate is constant.  This is the honest ``used_features`` bound for
-    ``kernels.common.block_heuristics`` and the per-tree compaction stat.
+    predicate is constant.  This is the per-tree compaction stat.
     """
     feat = np.asarray(jax.device_get(forest.feature))
     real = np.isfinite(np.asarray(jax.device_get(forest.threshold)))

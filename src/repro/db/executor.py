@@ -80,14 +80,6 @@ asserted on every acquire, and reported as ``ScanStats.max_in_flight``.
 The drain worker holds per-batch PREDICTIONS ([rows]-sized, not page
 buffers), bounded by the queue, so the invariant is unaffected.
 
-The preallocated result buffer also retires the jax-0.4.37 concatenate
-workaround from the hot path: per-batch outputs are written into host
-memory slot by slot, so the eager ``jnp.concatenate`` over PARTIALLY
-replicated operands (which XLA:CPU miscompiles by summing replicas) never
-runs.  ``tests/test_streaming.py`` keeps a pinned reproduction of the
-miscompile so a future jax bump can delete the note entirely; the host
-gather used here (per-shard copy + stitch) is not affected.
-
 See ``docs/architecture.md`` (tier ladder, drain pipeline),
 ``docs/reliability.md`` (fault sites, ladders, deadline contract) and
 ``docs/benchmarks.md`` (how the stats surface in BENCH_stream.json /
@@ -97,6 +89,7 @@ BENCH_faults.json).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import queue as queue_mod
 import threading
 import time
@@ -229,17 +222,21 @@ def _block_nbytes(block) -> int:
                if hasattr(x, "dtype"))
 
 
-def _pinned_host_sharding():
-    """A ``pinned_host`` single-device sharding when the backend has that
-    memory kind (TPU/GPU — where D2H through pinned staging is a real DMA
-    fast path), else None (XLA:CPU only exposes ``unpinned_host``)."""
+@functools.lru_cache(maxsize=None)
+def _pinned_host_sharding(device):
+    """A ``pinned_host`` sharding on ``device`` — the D2H staging target
+    of a prediction that lives there (TPU/GPU: a real DMA fast path).
+
+    Only an XLA:CPU build without the memory kind yields None.  An
+    accelerator without it raises: pinned staging must never switch off
+    in silence where it is the fast path."""
     try:
-        dev = jax.local_devices()[0]
-        dev.memory("pinned_host")     # raises if the kind doesn't exist
-        return jax.sharding.SingleDeviceSharding(
-            dev, memory_kind="pinned_host")
-    except Exception:
-        return None
+        device.memory("pinned_host")  # raises if the kind doesn't exist
+    except (RuntimeError, ValueError):
+        if device.platform == "cpu":
+            return None
+        raise
+    return jax.sharding.SingleDeviceSharding(device, memory_kind="pinned_host")
 
 
 class _ResultSink:
@@ -259,14 +256,13 @@ class _ResultSink:
     """
 
     def __init__(self, total_rows: int, page_rows: int,
-                 stats: ScanStats, pinned=None, *,
+                 stats: ScanStats, *,
                  injector: FaultInjector | None = None,
                  policy: RetryPolicy | None = None,
                  track_mask: bool = False):
         self.total_rows = total_rows
         self.page_rows = page_rows
         self.stats = stats
-        self.pinned = pinned
         self.injector = injector
         self.policy = policy
         self.result: np.ndarray | None = None    # allocated at first write
@@ -280,22 +276,27 @@ class _ResultSink:
         self.mask: np.ndarray | None = (
             np.zeros(total_rows, bool) if track_mask else None)
 
-    def wants_pinned(self, pred) -> bool:
-        """Pinned staging applies to single-device predictions only:
-        sharded mesh outputs take the per-shard host gather instead."""
-        return (self.pinned is not None
-                and getattr(pred, "sharding", None) is not None
-                and len(pred.sharding.device_set) == 1)
+    @staticmethod
+    def pinned_for(pred):
+        """The pinned staging sharding on ``pred``'s own device, or None.
+        Pinned staging applies to single-device predictions only: sharded
+        mesh outputs take the per-shard host gather instead."""
+        sharding = getattr(pred, "sharding", None)
+        if sharding is None or len(sharding.device_set) != 1:
+            return None
+        (device,) = sharding.device_set
+        return _pinned_host_sharding(device)
 
     def _write_once(self, first_page: int, num_pages: int, pred) -> None:
         t0 = time.perf_counter()
-        if self.wants_pinned(pred):
+        pinned = self.pinned_for(pred)
+        if pinned is not None:
             # D2H DMA into pinned staging; np.asarray of a pinned_host
             # array is then a cheap host-side view/copy.  This is the
             # ONLY transfer on this path — submit() skips the plain
             # copy_to_host_async for pinned-eligible predictions, else
             # every batch would pay the D2H twice.
-            pred = jax.device_put(pred, self.pinned)
+            pred = jax.device_put(pred, pinned)
             self.stats.pinned_staging = True
         host = np.asarray(pred)                  # per-shard copy + stitch
         if self.result is None:
@@ -531,7 +532,6 @@ class StreamingScanExecutor:
         # depth and flag the degradation separately
         depth = self.prefetch_depth
         sink = _ResultSink(source.num_pages * R, R, stats,
-                           pinned=_pinned_host_sharding(),
                            injector=self.injector,
                            policy=self.retry_policy,
                            track_mask=deadline is not None)
@@ -660,7 +660,7 @@ class StreamingScanExecutor:
             pinned path exists to save).  ``batch_span`` rides the queue
             item so the drain worker's ``scan.drain_write`` span nests
             under the owning batch even across the thread hop."""
-            if not sink.wants_pinned(pred) \
+            if sink.pinned_for(pred) is None \
                     and hasattr(pred, "copy_to_host_async"):
                 pred.copy_to_host_async()
             t0 = time.perf_counter()

@@ -31,6 +31,7 @@ import dataclasses
 import io
 import os
 import time
+import zlib
 
 import jax
 import jax.numpy as jnp
@@ -102,7 +103,9 @@ def synth_dataset(name: str, *, scale: float = 1.0, seed: int = 0,
     n = int(rows * scale)
     if max_rows is not None:
         n = min(n, max_rows)
-    rng = np.random.default_rng(seed + hash(name) % 2**16)
+    # crc32, not hash(): str hashing is salted per process, and seeded
+    # data must be the same in every process
+    rng = np.random.default_rng(seed + zlib.crc32(name.encode()) % 2**16)
     x = rng.normal(size=(n, F)).astype(np.float32)
     w = rng.normal(size=(min(F, 16),)).astype(np.float32)
     signal = x[:, : w.size] @ w

@@ -41,7 +41,7 @@ storage format is part of both cache keys).
 
 Multi-device fused inference: on a mesh (``dist.sharding.make_forest_plan``
 axis mapping: ``data`` shards sample blocks / CSR pages, ``model`` shards
-tree blocks) the kernel stages run under ``jax.experimental.shard_map``.
+tree blocks) the kernel stages run under ``jax.shard_map``.
 The udf plan's body is the fused kernel over the LOCAL sample shard with a
 replicated forest; the rel plan's cross-product + partial-aggregate
 collapse into ONE local fused kernel launch per device followed by a
@@ -68,9 +68,10 @@ preallocated host result buffer, so the D2H never blocks the next
 batch's kernels.  Device-tier datasets take the identical loop with a
 no-op transfer stage; disk-tier datasets feed it ``np.memmap`` page
 views, so a LIBSVM file larger than both the device and host budgets
-streams end to end.  The result buffer also retired the jax-0.4.37
-partially-replicated-concatenate workaround from the hot path (pinned
-reproduction in ``tests/test_streaming.py``).
+streams end to end.  On every tier the default batch is also bounded by
+the predictor's device working set (``PREDICT_BATCH_BYTES``), so a
+resident table is never scored in one whole-table batch whose
+intermediates would not fit device memory.
 
 Each stage is timed and its materialized bytes recorded, reproducing the
 paper's latency breakdowns.  See ``docs/architecture.md`` for the plan /
@@ -89,13 +90,11 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 
 from repro.core import algorithms as algs
 from repro.core import postprocess as post
-from repro.core.forest import (Forest, compact_forest, hb_path_matrix,
-                               pad_trees, qs_bitvectors, tree_slice)
+from repro.core.forest import Forest, compact_forest, pad_trees, tree_slice
 from repro.core.reuse import (GLOBAL_CACHE, GLOBAL_PLAN_CACHE,
                               MaterializedModel, ModelReuseCache,
                               fingerprint_forest, mesh_signature)
@@ -113,7 +112,7 @@ from repro.kernels.gather import csr_block_to_dense, gather_inverse_map
 from repro.kernels.ops import default_tree_block
 
 __all__ = ["QueryResult", "RowBatchResult", "CompiledQueryPlan",
-           "ForestQueryEngine"]
+           "ForestQueryEngine", "PREDICT_BATCH_BYTES", "predict_row_bytes"]
 
 #: sentinel occupying the DATASET slot (key[2]) of row-plan cache keys:
 #: row batches come from the serving plane, not a stored dataset, so
@@ -223,6 +222,35 @@ def _predict_sum_fn(algorithm: str):
         return FUSED_KERNEL_ALGORITHMS[algorithm], True
     predict = _predict_fn(algorithm)
     return (lambda forest, x: post.aggregate_raw(predict(forest, x))), False
+
+
+#: device bytes the predictor's intermediates may take per scan batch
+#: (a default batch is cut to fit; explicit ``batch_pages`` win)
+PREDICT_BATCH_BYTES = 1 << 30
+
+
+def predict_row_bytes(algorithm: str, forest: Forest, features: int) -> int:
+    """Device bytes one scanned row costs the predictor at its peak: the
+    row itself (and its batch copy), plus the algorithm's largest live
+    per-row intermediates.
+
+    fused kernels  one [rows] partial sum per tree (at most; rel plans)
+    unfused        the [rows, T] score matrix and its re-laid copy
+    hummingbird    [rows, T, I] predicates and [rows, T, L] paths
+    quickscorer    [rows, T, I, W] bit-vector masks
+    other jnp      [rows, T] index, gathered operands and scores
+    """
+    T, I, L = forest.num_trees, forest.num_internal, forest.num_leaves
+    row = 2 * 4 * features
+    if algorithm.endswith("_pallas_fused"):
+        return row + 4 * (T + 4)
+    if algorithm.endswith("_pallas"):
+        return row + 2 * 4 * T
+    if algorithm == "hummingbird":
+        return row + 2 * 4 * T * (I + L)
+    if algorithm == "quickscorer":
+        return row + 4 * T * I * ((L + 31) // 32) + 2 * 4 * T * I
+    return row + 8 * 4 * T
 
 
 class ForestQueryEngine:
@@ -344,12 +372,6 @@ class ForestQueryEngine:
             aux["inv_map"] = inv_map
             aux["f_used"] = f_used
         forest_p, true_T = pad_trees(forest, num_parts)
-        if "hummingbird" in algorithm:
-            C, D = hb_path_matrix(forest_p.depth)
-            aux["C"] = jnp.asarray(C, jnp.float32)
-            aux["D"] = jnp.asarray(D, jnp.float32)
-        if "quickscorer" in algorithm:
-            aux["bv"] = jnp.asarray(qs_bitvectors(forest_p.depth))
         spec = None
         shardings = self.fplan.forest_shardings(forest_p)
         if shardings is not None:
@@ -390,10 +412,10 @@ class ForestQueryEngine:
                 def body(x_local, f_local, inv_local):
                     return predict_sum(f_local, x_local)
 
-            sm = shard_map(body, mesh=fplan.mesh,
-                           in_specs=(fplan.x_spec, fplan.replicated_spec,
-                                     fplan.replicated_spec),
-                           out_specs=fplan.out_spec, check_rep=False)
+            sm = jax.shard_map(body, mesh=fplan.mesh,
+                               in_specs=(fplan.x_spec, fplan.replicated_spec,
+                                         fplan.replicated_spec),
+                               out_specs=fplan.out_spec, check_vma=False)
 
             def udf(state):
                 state = dict(state)
@@ -459,10 +481,10 @@ class ForestQueryEngine:
                 part = predict_sum(f_local, x_local)       # [B_local]
                 return jax.lax.psum(part, model_axis)
 
-            sm = shard_map(body, mesh=fplan.mesh,
-                           in_specs=(fplan.x_spec, fplan.tree_spec,
-                                     fplan.replicated_spec),
-                           out_specs=fplan.out_spec, check_rep=False)
+            sm = jax.shard_map(body, mesh=fplan.mesh,
+                               in_specs=(fplan.x_spec, fplan.tree_spec,
+                                         fplan.replicated_spec),
+                               out_specs=fplan.out_spec, check_vma=False)
 
             def cross_product(state):
                 state = dict(state)
@@ -835,6 +857,16 @@ class ForestQueryEngine:
                 fit = target // max(ds.page_nbytes, 1)
                 batch_pages = min(ds.num_pages,
                                   max(unit, fit // unit * unit))
+            # every tier: the predictor's device working set bounds the
+            # batch (a resident table scored in one batch would hold
+            # [rows, T]-sized intermediates for all of its rows)
+            unit = max(1, self.fplan.n_data)
+            features = ds.num_features if fmt == "dense" else min(
+                ds.num_features, forest.num_trees * forest.num_internal)
+            page_cost = ds.page_rows * predict_row_bytes(
+                algorithm, forest, features)
+            fit = PREDICT_BATCH_BYTES // max(page_cost, 1)
+            batch_pages = min(batch_pages, max(unit, fit // unit * unit))
         if self.fplan.n_data > 1:
             # shard_map needs page batches that divide evenly over the
             # data axis; num_pages itself is a data-axis multiple (the
@@ -956,9 +988,8 @@ class ForestQueryEngine:
         # every plan/format/tier.  Host-tier pages double-buffer their
         # DMA against the kernel stages; device-tier datasets take the
         # no-op transfer stage.  Per-batch predictions land in the
-        # executor's preallocated host buffer — no concatenate (and no
-        # jax-0.4.37 partially-replicated-concatenate workaround) on the
-        # hot path.
+        # executor's preallocated host buffer — no concatenate on the hot
+        # path.
         executor = StreamingScanExecutor(
             qplan.stages,
             sharding=self.store.data_sharding(),

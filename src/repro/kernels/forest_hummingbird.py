@@ -6,19 +6,20 @@ memory between GEMMs; those intermediates are the largest tensors in the
 whole computation (B*T*(I+L) words vs B*F + T*(I+L) for the inputs).
 
 TPU adaptation (DESIGN.md Sec. 3): fuse all three stages into one kernel so S
-and P live only in VMEM, per (sample-tile x tree-tile):
+and P live only in VMEM, one tree at a time per (sample-tile x tree-tile):
 
   1. S  = dense predicate eval                 (MXU, gather-free, common.py)
-  2. P  = S @ C                                (MXU; C is the [I, L]
+  2. P  = S @ C                                (MXU; C is the [Ip, Lp]
           structure-only path matrix shared by ALL trees of a depth - a
           consequence of the dense complete-tree layout, so it is loaded
           once, not per tree)
-  3. out = sum_l (P == D[l]) * leaf_value[t,l] (VPU compare + MXU dot)
+  3. out = sum_l (P == D[l]) * leaf_value[t,l] (VPU compare + lane sum)
 
-HBM traffic per tile drops from (read S + write S + read P + write P) to
-zero — the roofline win measured in EXPERIMENTS.md §Perf.
+S is 0/1 and C is -1/0/1, so the path GEMM runs on bf16 operands and is
+exact.  HBM traffic per tile drops from (read S + write S + read P + write
+P) to zero.
 
-FUSED variant (``hummingbird_fused_kernel_call``): the remaining [B, T]
+FUSED variant (``common.forest_call(fused=True)``): the remaining [B, T]
 score write is folded away too — the tree grid axis accumulates each tile's
 per-sample partial sum into one revisited [BB, 1] output block (init at
 j == 0), so phase 2 never touches HBM.
@@ -26,121 +27,16 @@ j == 0), so phase 2 never touches HBM.
 
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.kernels.common import dense_predicates
-
-__all__ = ["hummingbird_kernel_call", "hummingbird_fused_kernel_call"]
+__all__ = ["hummingbird_tree_score"]
 
 
-def _tile_scores(x_ref, feat_ref, thr_ref, dl_ref, leaf_ref, c_ref, d_ref,
-                 *, acc_dtype=jnp.float32):
-    """One (sample tile x tree tile) of raw per-tree scores [BB, BT].
+def hummingbird_tree_score(s, leaves, C, D):
+    """s [BB, Ip] f32 0/1, leaves [1, Lp] -> exit-leaf values [BB, 1].
 
-    Tree tiles (thresholds/leaves) may be staged bf16; compute accumulates
-    at ``acc_dtype`` (f32) — leaves upcast on load, C/D stay f32 (they are
-    structure-only, shared across trees, and the P == D leaf match needs
-    exact small-integer counts).
-    """
-    x = x_ref[...]                        # [BB, F]
-    feat = feat_ref[...]                  # [BT, I]
-    thr = thr_ref[...]
-    dl = dl_ref[...] != 0
-    leaves = leaf_ref[...].astype(acc_dtype)   # [BT, L] upcast on load
-    C = c_ref[...]                        # [I, L] shared structure matrix
-    D = d_ref[...]                        # [1, L] left-turn counts per leaf
-    BB = x.shape[0]
-    BT, I = feat.shape
-    L = C.shape[1]
-
-    s = dense_predicates(x, feat, thr, dl,
-                         acc_dtype=acc_dtype).astype(acc_dtype)  # [BB, BT, I]
-    # stage 2: path GEMM against the shared C — one [BB*BT, I] @ [I, L]
-    P = jnp.dot(s.reshape(BB * BT, I), C,
-                preferred_element_type=acc_dtype)                # [BB*BT, L]
-    # stage 3: exit-leaf one-hot (P == D) and leaf-value contraction
-    onehot = (P == D).astype(acc_dtype).reshape(BB, BT, L)
-    return jnp.sum(onehot * leaves[None], axis=2)
-
-
-def _kernel(x_ref, feat_ref, thr_ref, dl_ref, leaf_ref, c_ref, d_ref, out_ref,
-            *, acc_dtype=jnp.float32):
-    out_ref[...] = _tile_scores(x_ref, feat_ref, thr_ref, dl_ref, leaf_ref,
-                                c_ref, d_ref, acc_dtype=acc_dtype)
-
-
-def _fused_kernel(x_ref, feat_ref, thr_ref, dl_ref, leaf_ref, c_ref, d_ref,
-                  out_ref, *, acc_dtype=jnp.float32):
-    scores = _tile_scores(x_ref, feat_ref, thr_ref, dl_ref, leaf_ref,
-                          c_ref, d_ref, acc_dtype=acc_dtype)
-
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] += jnp.sum(scores, axis=1, keepdims=True)
-
-
-def _in_specs(F, I, L, W_unused, block_b, block_t):
-    return [
-        pl.BlockSpec((block_b, F), lambda i, j: (i, 0)),
-        pl.BlockSpec((block_t, I), lambda i, j: (j, 0)),
-        pl.BlockSpec((block_t, I), lambda i, j: (j, 0)),
-        pl.BlockSpec((block_t, I), lambda i, j: (j, 0)),
-        pl.BlockSpec((block_t, L), lambda i, j: (j, 0)),
-        pl.BlockSpec((I, L), lambda i, j: (0, 0)),
-        pl.BlockSpec((1, L), lambda i, j: (0, 0)),
-    ]
-
-
-def hummingbird_kernel_call(x, feature, threshold, default_left, leaf_value,
-                            C, D, *, block_b, block_t, interpret=False,
-                            acc_dtype=jnp.float32):
-    """Raw pallas_call; shapes must already be padded to block multiples.
-
-    C [I, L] f32 and D [1, L] f32 are the structure-only tensors from
-    ``core.forest.hb_path_matrix`` (shared across trees of one depth).
-    """
-    B, F = x.shape
-    T, I = feature.shape
-    L = leaf_value.shape[1]
-    assert B % block_b == 0 and T % block_t == 0
-    grid = (B // block_b, T // block_t)
-
-    kernel = functools.partial(_kernel, acc_dtype=acc_dtype)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=_in_specs(F, I, L, None, block_b, block_t),
-        out_specs=pl.BlockSpec((block_b, block_t), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((B, T), acc_dtype),
-        interpret=interpret,
-    )(x, feature, threshold, default_left.astype(jnp.int8), leaf_value, C, D)
-
-
-def hummingbird_fused_kernel_call(x, feature, threshold, default_left,
-                                  leaf_value, C, D, *, block_b, block_t,
-                                  interpret=False, acc_dtype=jnp.float32):
-    """Fused GEMM traversal + SUM aggregation: returns [B, 1] sums.
-
-    Padding trees carry zero leaves, so they contribute exactly 0.0.
-    bf16 tree tiles upcast in-kernel; sums accumulate at ``acc_dtype``."""
-    B, F = x.shape
-    T, I = feature.shape
-    L = leaf_value.shape[1]
-    assert B % block_b == 0 and T % block_t == 0
-    grid = (B // block_b, T // block_t)
-
-    kernel = functools.partial(_fused_kernel, acc_dtype=acc_dtype)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=_in_specs(F, I, L, None, block_b, block_t),
-        out_specs=pl.BlockSpec((block_b, 1), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 1), acc_dtype),
-        interpret=interpret,
-    )(x, feature, threshold, default_left.astype(jnp.int8), leaf_value, C, D)
+    C [Ip, Lp] bf16 and D [1, Lp] f32 are the lane-padded structure-only
+    tensors from ``core.forest.hb_path_matrix`` (``ops._hb_tensors``)."""
+    P = jnp.dot(s.astype(jnp.bfloat16), C,
+                preferred_element_type=jnp.float32)          # [BB, Lp]
+    return jnp.sum(jnp.where(P == D, leaves, 0.0), axis=1, keepdims=True)

@@ -1,23 +1,25 @@
 """Pallas TPU kernel: predicated (branch-free) forest traversal.
 
 Paper Fig. 2(c) / Nvidia FIL adapted to the TPU (DESIGN.md Sec. 3): a tile of
-samples [BB, F] and a tile of trees (node arrays [BT, I]) are staged in VMEM;
-all node predicates are evaluated densely on the MXU once (gather-free,
-``common.dense_predicates``), then the fixed-depth descent
+samples [BB, F] and a tile of trees (node arrays [BT, Ip]) are staged in
+VMEM; for each tree of the tile all node predicates are evaluated densely on
+the MXU (gather-free, ``common.node_predicates``), then the fixed-depth
+descent
 
-    idx_{d+1} = 2*idx_d + 1 + (1 - s[b, t, idx_d])
+    idx_{d+1} = 2*idx_d + 1 + (1 - s[b, idx_d])
 
 runs as ``depth`` unrolled VPU steps, where the data-dependent fetch
-``s[b, t, idx]`` is an iota-compare masked sum (``common.onehot_select``) —
-the FIL predication trick with the pointer arithmetic replaced by lane
-arithmetic.  The exit-leaf fetch is one more one-hot select over L.
+``s[b, idx]`` is a lane-iota compare + masked lane sum — the FIL predication
+trick with the pointer arithmetic replaced by lane arithmetic.  The
+exit-leaf fetch is one more masked lane sum over the leaves.
 
-Grid: (ceil(B/BB), ceil(T/BT)); each program writes one [BB, BT] tile of raw
-per-tree scores.  Tree tiles are independent => the tree axis can be sharded
-across the mesh 'model' axis (relation-centric plan) with this same kernel.
+Grid: (ceil(B/BB), ceil(T/BT)).  The unfused kernel writes one [BB, BT]
+tile of raw per-tree scores.  Tree tiles are independent => the tree axis
+can be sharded across the mesh 'model' axis (relation-centric plan) with
+this same kernel.
 
-FUSED variant (``predicated_fused_kernel_call``): phase-2 aggregation moves
-INTO the kernel.  The tree grid axis j revisits one [BB, 1] output block per
+FUSED variant (``common.forest_call(fused=True)``): phase-2 aggregation
+moves INTO the kernel.  The tree grid axis j revisits one [BB, 1] output block per
 sample tile (initialized at j == 0), accumulating each tree tile's partial
 sum in VMEM — the [B, T] per-tree score matrix never exists in HBM, which is
 the data-movement term the paper's stage-materialization analysis charges
@@ -26,125 +28,22 @@ the unfused path with (Sec. 3.3).
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.kernels.common import dense_predicates, onehot_select
-
-__all__ = ["predicated_kernel_call", "predicated_fused_kernel_call"]
+__all__ = ["predicated_tree_score"]
 
 
-def _tile_scores(x_ref, feat_ref, thr_ref, dl_ref, leaf_ref, *, depth,
-                 acc_dtype=jnp.float32):
-    """One (sample tile x tree tile) of raw per-tree scores [BB, BT].
-
-    Tree tiles (thresholds/leaves) may be staged at a narrower dtype
-    (bf16 halves their VMEM footprint and HBM bandwidth); all compute
-    accumulates at ``acc_dtype`` (f32) — values upcast on load here.
-    """
-    x = x_ref[...]                       # [BB, F]
-    feat = feat_ref[...]                 # [BT, I]
-    thr = thr_ref[...]
-    dl = dl_ref[...] != 0                # int8 -> bool
-    leaves = leaf_ref[...].astype(acc_dtype)   # [BT, L] upcast on load
-    BB = x.shape[0]
-    BT, I = feat.shape
-
-    s = dense_predicates(x, feat, thr, dl, acc_dtype=acc_dtype)
-    s_val = s.astype(acc_dtype)                     # [BB, BT, I]
-
-    idx = jnp.zeros((BB, BT), jnp.int32)
+def predicated_tree_score(s, leaves, *, depth):
+    """s [BB, Ip] f32 0/1, leaves [1, Lp] f32 -> exit-leaf values [BB, 1]."""
+    BB, Ip = s.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (BB, Ip), 1)
+    idx = jnp.zeros((BB, 1), jnp.int32)
     for _ in range(depth):                          # unrolled descent
-        # go_left = s[b, t, idx]  via per-(b,t) one-hot select over I
-        n_iota = jax.lax.broadcasted_iota(jnp.int32, (BB, BT, I), 2)
-        mask = idx[:, :, None] == n_iota
-        go_left = jnp.sum(jnp.where(mask, s_val, 0.0), axis=2)
+        go_left = jnp.sum(jnp.where(lane == idx, s, 0.0), axis=1,
+                          keepdims=True)
         idx = 2 * idx + 1 + (1 - go_left.astype(jnp.int32))
-
-    leaf = idx - I                                  # [BB, BT] in [0, L)
-    return onehot_select(leaves, leaf)
-
-
-def _kernel(x_ref, feat_ref, thr_ref, dl_ref, leaf_ref, out_ref, *, depth,
-            acc_dtype=jnp.float32):
-    out_ref[...] = _tile_scores(x_ref, feat_ref, thr_ref, dl_ref, leaf_ref,
-                                depth=depth, acc_dtype=acc_dtype)
-
-
-def _fused_kernel(x_ref, feat_ref, thr_ref, dl_ref, leaf_ref, out_ref,
-                  *, depth, acc_dtype=jnp.float32):
-    scores = _tile_scores(x_ref, feat_ref, thr_ref, dl_ref, leaf_ref,
-                          depth=depth, acc_dtype=acc_dtype)
-
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] += jnp.sum(scores, axis=1, keepdims=True)
-
-
-def _forest_in_specs(F, I, L, block_b, block_t):
-    return [
-        pl.BlockSpec((block_b, F), lambda i, j: (i, 0)),
-        pl.BlockSpec((block_t, I), lambda i, j: (j, 0)),
-        pl.BlockSpec((block_t, I), lambda i, j: (j, 0)),
-        pl.BlockSpec((block_t, I), lambda i, j: (j, 0)),
-        pl.BlockSpec((block_t, L), lambda i, j: (j, 0)),
-    ]
-
-
-def predicated_kernel_call(x, feature, threshold, default_left, leaf_value,
-                           *, depth, block_b, block_t, interpret=False,
-                           acc_dtype=jnp.float32):
-    """Raw pallas_call; shapes must already be padded to block multiples."""
-    B, F = x.shape
-    T, I = feature.shape
-    L = leaf_value.shape[1]
-    assert B % block_b == 0 and T % block_t == 0
-    grid = (B // block_b, T // block_t)
-
-    kernel = functools.partial(_kernel, depth=depth, acc_dtype=acc_dtype)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=_forest_in_specs(F, I, L, block_b, block_t),
-        out_specs=pl.BlockSpec((block_b, block_t), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((B, T), acc_dtype),
-        interpret=interpret,
-    )(x, feature, threshold, default_left.astype(jnp.int8), leaf_value)
-
-
-def predicated_fused_kernel_call(x, feature, threshold, default_left,
-                                 leaf_value, *, depth, block_b, block_t,
-                                 interpret=False, acc_dtype=jnp.float32):
-    """Fused traversal + SUM aggregation: returns [B, 1] per-sample sums.
-
-    The tree grid axis is the accumulation axis: its output block index map
-    is constant in j, so the same [BB, 1] block is revisited for every tree
-    tile and accumulated in place (init at j == 0).  Padding trees carry
-    zero leaves, so they add exactly 0.0 to the sum.
-
-    Tree tiles (threshold/leaf_value) may arrive bf16 (InTreeger-style
-    shrink: half the tree-tile VMEM + HBM bandwidth); accumulation stays
-    at ``acc_dtype`` (f32) — the output block and every partial sum hold
-    full precision.
-    """
-    B, F = x.shape
-    T, I = feature.shape
-    L = leaf_value.shape[1]
-    assert B % block_b == 0 and T % block_t == 0
-    grid = (B // block_b, T // block_t)
-
-    kernel = functools.partial(_fused_kernel, depth=depth,
-                               acc_dtype=acc_dtype)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=_forest_in_specs(F, I, L, block_b, block_t),
-        out_specs=pl.BlockSpec((block_b, 1), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 1), acc_dtype),
-        interpret=interpret,
-    )(x, feature, threshold, default_left.astype(jnp.int8), leaf_value)
+    leaf = idx - ((1 << depth) - 1)                 # [BB, 1] in [0, L)
+    leaf_lane = jax.lax.broadcasted_iota(jnp.int32, (BB, leaves.shape[1]), 1)
+    return jnp.sum(jnp.where(leaf_lane == leaf, leaves, 0.0), axis=1,
+                   keepdims=True)

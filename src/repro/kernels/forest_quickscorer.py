@@ -12,11 +12,15 @@ TPU adaptation (DESIGN.md Sec. 3/6.2): evaluate ALL predicates densely on the
 MXU (the evaluation QuickScorer works to avoid is nearly free on a systolic
 array), then per 32-bit word
 
-    surviving[b, t, w] = AND_i ( s_false[b,t,i] ? bv[i, w] : 0xFFFFFFFF )
+    surviving[b, w] = AND_i ( s_false[b,i] ? bv[w, i] : 0xFFFFFFFF )
 
-as a log-depth halving tree on the VPU.  The ≤64-leaf limit disappears:
-depth-8 trees use W = 8 words.  Find-lowest-set-bit is a bit-expansion +
-cumsum==1 mask (no argmax), fused with the leaf-value contraction.
+as a log-depth butterfly of lane rotations (``pltpu.roll``) on the VPU,
+which leaves the AND replicated on every lane.  The ≤64-leaf limit
+disappears: depth-8 trees use W = 8 words.  Each leaf lane then reads its
+own bit of its own word, and the exit leaf is the lowest lane whose bit is
+set (a lane min, no argmax), fused with the leaf-value select.  The words
+are int32 bit patterns: the TPU has no unsigned-to-float conversion, and
+none is needed.
 
 The bit-vectors are STRUCTURE-ONLY for the dense complete layout (identical
 for every tree of a depth) => loaded once per kernel, not per tree.
@@ -24,157 +28,45 @@ for every tree of a depth) => loaded once per kernel, not per tree.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import dense_predicates
-
-__all__ = ["quickscorer_kernel_call", "quickscorer_fused_kernel_call"]
+__all__ = ["quickscorer_tree_score"]
 
 
-def _and_reduce(masks):
-    """[BB, BT, n] uint32 -> [BB, BT] AND over last axis (n a power of 2)."""
-    while masks.shape[2] > 1:
-        h = masks.shape[2] // 2
-        masks = jnp.bitwise_and(masks[:, :, :h], masks[:, :, h:])
-    return masks[:, :, 0]
+def _and_all_lanes(m):
+    """[BB, n] int32 -> AND over the lane axis, replicated on every lane
+    (n a power of two)."""
+    shift = m.shape[1] // 2
+    while shift:
+        m = jnp.bitwise_and(m, pltpu.roll(m, shift, 1))
+        shift //= 2
+    return m
 
 
-def _tile_scores(x_ref, feat_ref, thr_ref, dl_ref, leaf_ref, bv_ref,
-                 *, num_words, acc_dtype=jnp.float32):
-    """One (sample tile x tree tile) of raw per-tree scores [BB, BT].
+def quickscorer_tree_score(s, leaves, bv):
+    """s [BB, Ip] f32 (1.0 = go left), leaves [1, Lp], bv [W, Ip] int32
+    -> exit-leaf values [BB, 1].
 
-    Tree tiles (thresholds/leaves) may be staged bf16; the bit-vector
-    machinery is uint32 regardless, and the leaf contraction accumulates
-    at ``acc_dtype`` (f32) after an on-load upcast.
-    """
-    x = x_ref[...]                        # [BB, F]
-    feat = feat_ref[...]                  # [BT, I]
-    thr = thr_ref[...]
-    dl = dl_ref[...] != 0
-    leaves = leaf_ref[...].astype(acc_dtype)   # [BT, L] upcast on load
-    bv = bv_ref[...]                      # [I, W] uint32 (structure-only)
-    BB = x.shape[0]
-    BT, I = feat.shape
-    L = leaves.shape[1]
-    W = num_words
-
-    s_false = ~dense_predicates(x, feat, thr, dl,
-                                acc_dtype=acc_dtype)     # [BB, BT, I]
-
-    # pad the node axis to a power of two with identity masks
-    n = 1
-    while n < I:
-        n *= 2
-    ones = jnp.uint32(0xFFFFFFFF)
-
-    bit_planes = []
-    for w in range(W):                                   # static unroll
-        bv_w = bv[:, w]                                  # [I]
-        m = jnp.where(s_false, bv_w[None, None, :], ones)  # [BB, BT, I]
-        if n != I:
-            m = jnp.concatenate(
-                [m, jnp.full((BB, BT, n - I), ones, jnp.uint32)], axis=2)
-        surv = _and_reduce(m)                            # [BB, BT] uint32
-        # expand the word into 32 LSB-first bit lanes
-        shifts = jax.lax.broadcasted_iota(jnp.uint32, (BB, BT, 32), 2)
-        bits = jax.lax.shift_right_logical(surv[:, :, None], shifts)
-        bit_planes.append(jnp.bitwise_and(bits, jnp.uint32(1)))
-    bits = jnp.concatenate(bit_planes, axis=2).astype(jnp.float32)
-    # lanes beyond L are phantom (never cleared, so always 1) — but the real
-    # exit leaf (< L) always survives and is lower, so slicing is exact.
-    bits = bits[:, :, :L]                                # [BB, BT, L]
-
-    # lowest set bit: bit set AND cumulative count == 1 (no argmax needed)
-    first = bits * (jnp.cumsum(bits, axis=2) == 1.0)
-    return jnp.sum(first * leaves[None], axis=2)
-
-
-def _kernel(x_ref, feat_ref, thr_ref, dl_ref, leaf_ref, bv_ref, out_ref,
-            *, num_words, acc_dtype=jnp.float32):
-    out_ref[...] = _tile_scores(x_ref, feat_ref, thr_ref, dl_ref, leaf_ref,
-                                bv_ref, num_words=num_words,
-                                acc_dtype=acc_dtype)
-
-
-def _fused_kernel(x_ref, feat_ref, thr_ref, dl_ref, leaf_ref, bv_ref,
-                  out_ref, *, num_words, acc_dtype=jnp.float32):
-    scores = _tile_scores(x_ref, feat_ref, thr_ref, dl_ref, leaf_ref,
-                          bv_ref, num_words=num_words, acc_dtype=acc_dtype)
-
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] += jnp.sum(scores, axis=1, keepdims=True)
-
-
-def _in_specs(F, I, L, W, block_b, block_t):
-    return [
-        pl.BlockSpec((block_b, F), lambda i, j: (i, 0)),
-        pl.BlockSpec((block_t, I), lambda i, j: (j, 0)),
-        pl.BlockSpec((block_t, I), lambda i, j: (j, 0)),
-        pl.BlockSpec((block_t, I), lambda i, j: (j, 0)),
-        pl.BlockSpec((block_t, L), lambda i, j: (j, 0)),
-        pl.BlockSpec((I, W), lambda i, j: (0, 0)),
-    ]
-
-
-def quickscorer_kernel_call(x, feature, threshold, default_left, leaf_value,
-                            bitvectors, *, block_b, block_t, interpret=False,
-                            acc_dtype=jnp.float32):
-    """Raw pallas_call; shapes must already be padded to block multiples.
-
-    bitvectors [I, W] uint32 from ``core.forest.qs_bitvectors``.
-    """
-    B, F = x.shape
-    T, I = feature.shape
-    L = leaf_value.shape[1]
-    W = bitvectors.shape[1]
-    assert B % block_b == 0 and T % block_t == 0
-    assert W * 32 >= L, f"bit width {W*32} < leaves {L}"
-    grid = (B // block_b, T // block_t)
-
-    kernel = functools.partial(_kernel, num_words=W, acc_dtype=acc_dtype)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=_in_specs(F, I, L, W, block_b, block_t),
-        out_specs=pl.BlockSpec((block_b, block_t), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((B, T), acc_dtype),
-        interpret=interpret,
-    )(x, feature, threshold, default_left.astype(jnp.int8), leaf_value,
-      bitvectors)
-
-
-def quickscorer_fused_kernel_call(x, feature, threshold, default_left,
-                                  leaf_value, bitvectors, *, block_b,
-                                  block_t, interpret=False,
-                                  acc_dtype=jnp.float32):
-    """Fused bit-vector traversal + SUM aggregation: returns [B, 1] sums.
-
-    The tree grid axis revisits one [BB, 1] output block per sample tile
-    (init at j == 0); padding trees carry zero leaves so they add 0.0.
-    bf16 tree tiles upcast in-kernel; sums accumulate at ``acc_dtype``."""
-    B, F = x.shape
-    T, I = feature.shape
-    L = leaf_value.shape[1]
-    W = bitvectors.shape[1]
-    assert B % block_b == 0 and T % block_t == 0
-    assert W * 32 >= L, f"bit width {W*32} < leaves {L}"
-    grid = (B // block_b, T // block_t)
-
-    kernel = functools.partial(_fused_kernel, num_words=W,
-                               acc_dtype=acc_dtype)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=_in_specs(F, I, L, W, block_b, block_t),
-        out_specs=pl.BlockSpec((block_b, 1), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 1), acc_dtype),
-        interpret=interpret,
-    )(x, feature, threshold, default_left.astype(jnp.int8), leaf_value,
-      bitvectors)
+    ``bv`` is ``core.forest.qs_bitvectors`` transposed, bit-cast and
+    lane-padded with all-ones columns (``ops._qs_tensors``)."""
+    BB, Lp = s.shape[0], leaves.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (BB, Lp), 1)
+    word_of_lane = jax.lax.shift_right_logical(lane, 5)          # lane // 32
+    words = jnp.zeros((BB, Lp), jnp.int32)
+    for w in range(bv.shape[0]):                     # static unroll
+        # a TRUE (left) node clears nothing: it contributes all ones
+        m = _and_all_lanes(jnp.where(s > 0.5, jnp.int32(-1),
+                                     bv[w:w + 1, :]))
+        words = jnp.where(word_of_lane == w, m, words)
+    bits = jnp.bitwise_and(
+        jax.lax.shift_right_logical(words, jnp.bitwise_and(lane, 31)), 1)
+    # lanes past the real leaves are phantom (their bits are never
+    # cleared, or belong to no word) — but the real exit leaf always
+    # survives and is lower, so the lowest set lane is exact
+    lane_f = lane.astype(jnp.float32)
+    exit_leaf = jnp.min(jnp.where(bits == 1, lane_f, float(Lp)), axis=1,
+                        keepdims=True)
+    return jnp.sum(jnp.where(lane_f == exit_leaf, leaves, 0.0), axis=1,
+                   keepdims=True)

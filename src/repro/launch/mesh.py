@@ -10,16 +10,20 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["make_production_mesh", "make_local_mesh", "V5E"]
+__all__ = ["make_production_mesh", "make_local_mesh", "V5E", "TPU_PEAKS"]
 
 
-# TPU v5e hardware constants used by the roofline (per chip).
+# TPU v5e hardware constants used by the roofline (per chip; Google Cloud
+# documentation, "TPU v5e").
 V5E = {
     "peak_flops_bf16": 197e12,     # FLOP/s
     "hbm_bandwidth": 819e9,        # B/s
     "ici_bandwidth": 50e9,         # B/s per link
     "hbm_bytes": 16 * 1024**3,
 }
+
+#: published per-chip peaks keyed by ``jax.Device.device_kind``
+TPU_PEAKS = {"TPU v5 lite": V5E}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -23,7 +23,7 @@ import re
 import time
 from typing import Any
 
-from repro.launch.mesh import V5E
+from repro.launch.mesh import TPU_PEAKS, V5E
 
 __all__ = ["CollectiveStats", "parse_collectives", "roofline_terms",
            "model_flops", "calibrate_peaks", "resolve_peaks"]
@@ -151,7 +151,7 @@ def model_flops(cfg, shape) -> float:
 # ``calibrate_peaks`` measures the *effective* peaks of the live backend
 # once per process with a one-shot microbenchmark and caches the result;
 # ``resolve_peaks`` is the lookup the optimizer uses (TPU → the published
-# v5e table, anything else → the calibrated table).
+# table of its device kind, anything else → the calibrated table).
 # ---------------------------------------------------------------------------
 
 _CALIBRATED: dict[str, dict[str, float]] = {}
@@ -252,18 +252,26 @@ def calibrate_peaks(backend: str | None = None, *,
 def resolve_peaks(backend: str | None = None) -> dict[str, float]:
     """Peaks table for cost ranking on the live backend.
 
-    TPU backends get the published v5e table (augmented with derived
-    gather/h2d/dispatch entries); everything else gets the one-shot
+    TPU backends get the published table of their ``device_kind``
+    (``mesh.TPU_PEAKS``; a kind not in it is an error, not a default),
+    augmented with ASSUMED gather/h2d/dispatch entries — the table says
+    so with ``measured=False``.  Everything else gets the one-shot
     calibrated table from :func:`calibrate_peaks`.
     """
     import jax
     backend = backend or jax.default_backend()
     if backend == "tpu":
-        peaks = dict(V5E)
-        peaks.setdefault("gather_bandwidth", V5E["hbm_bandwidth"] / 8)
+        kind = jax.devices()[0].device_kind
+        if kind not in TPU_PEAKS:
+            raise ValueError(
+                f"no published peaks for TPU device kind {kind!r}; "
+                f"known kinds: {sorted(TPU_PEAKS)}")
+        peaks = dict(TPU_PEAKS[kind])
+        peaks.setdefault("gather_bandwidth", peaks["hbm_bandwidth"] / 8)
         peaks.setdefault("h2d_bandwidth", 25e9)      # PCIe-class
         peaks.setdefault("dispatch_s", 5e-6)
         peaks["backend"] = "tpu"
+        peaks["device_kind"] = kind
         peaks["measured"] = False
         return peaks
     return calibrate_peaks(backend)

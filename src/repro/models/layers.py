@@ -484,7 +484,6 @@ def apply_moe(cfg: ModelConfig, p: Params, x: jax.Array, *,
         out = _moe_dispatch_compute(p, x.reshape(B * S, D), cap, ep_axis=None)
         out = out.reshape(B, S, D)
     else:
-        from jax.experimental.shard_map import shard_map
         n_model = mesh.shape["model"]
         data_axes = splan.data_axes
         # local tokens per (data..., model) block (activations are CP-sharded
@@ -507,9 +506,9 @@ def apply_moe(cfg: ModelConfig, p: Params, x: jax.Array, *,
                     P("model", None, None),               # wi (E sharded)
                     P("model", None, None),               # wg
                     P("model", None, None))               # wo
-        out = shard_map(local, mesh=mesh, in_specs=in_specs,
-                        out_specs=P(da, "model", None),
-                        check_rep=False)(
+        out = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                            out_specs=P(da, "model", None),
+                            check_vma=False)(
             x, p["router"], p["wi"], p["wg"], p["wo"])
 
     if cfg.shared_expert:
@@ -537,7 +536,6 @@ def moe_decode(cfg: ModelConfig, p: Params, x: jax.Array,
               and "model" in mesh.axis_names
               and cfg.num_experts % mesh.shape["model"] == 0)
     if use_ep:
-        from jax.experimental.shard_map import shard_map
         n = mesh.shape["model"]
         E = cfg.num_experts
         E_l = E // n
@@ -566,12 +564,12 @@ def moe_decode(cfg: ModelConfig, p: Params, x: jax.Array,
             y = y * gate[:, None].astype(y.dtype)
             return jax.lax.psum(y.reshape(b, s, d), "model")
 
-        out = shard_map(
+        out = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(b_spec, None, None), P(),
                       P("model", None, None), P("model", None, None),
                       P("model", None, None)),
-            out_specs=P(b_spec, None, None), check_rep=False)(
+            out_specs=P(b_spec, None, None), check_vma=False)(
             x, p["router"], p["wi"], p["wg"], p["wo"])
     else:
         tokens = x.reshape(B * S, D)

@@ -256,6 +256,24 @@ def test_batching_equivalence(setup):
                                np.asarray(whole.predictions), rtol=1e-6)
 
 
+def test_device_tier_batch_bounded_by_predictor(setup, monkeypatch):
+    """A resident table is not scored in one batch when the predictor's
+    intermediates for all of its rows would pass ``PREDICT_BATCH_BYTES``:
+    the default batch shrinks to what fits, with the one-batch answer."""
+    from repro.db import query
+    store, forest, x = setup
+    engine = ForestQueryEngine(store, reuse_cache=ModelReuseCache())
+    whole = engine.infer("test", forest, plan="udf")
+    assert whole.scan.batches == 1
+    page_cost = 64 * query.predict_row_bytes("predicated", forest,
+                                             x.shape[1])
+    monkeypatch.setattr(query, "PREDICT_BATCH_BYTES", 2 * page_cost)
+    cut = engine.infer("test", forest, plan="udf")
+    assert cut.scan.batch_pages == 2 and cut.scan.batches == 3
+    np.testing.assert_allclose(np.asarray(cut.predictions),
+                               np.asarray(whole.predictions), rtol=1e-6)
+
+
 def test_write_operator(setup):
     store, forest, _ = setup
     engine = ForestQueryEngine(store, reuse_cache=ModelReuseCache())
@@ -274,6 +292,34 @@ def test_store_page_padding():
     # padded rows are NaN (never counted in results)
     tail = np.asarray(ds.data)[100:]
     assert np.isnan(tail).all()
+
+
+def test_synth_dataset_same_in_every_process():
+    """Seeded data does not depend on the per-process str-hash salt: these
+    values are pinned, so every process draws them."""
+    x, y = synth_dataset("higgs", seed=0, max_rows=4)
+    np.testing.assert_array_equal(
+        x[0, :3], np.array([-1.316493, -1.6562703, 1.0178546], np.float32))
+    np.testing.assert_array_equal(y, np.array([0, 0, 1, 1], np.float32))
+
+
+def test_compile_cache_dir_env_or_checkout(monkeypatch, tmp_path):
+    """The persistent compile cache lives where the environment says, or
+    at the fixed ``.jax_cache`` of the checkout — nowhere else."""
+    import jax
+    from repro import compile_cache
+    saved = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == saved   # JAX reads env
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = compile_cache.enable_compile_cache()
+        assert path == str(compile_cache.CHECKOUT / ".jax_cache")
+        assert (compile_cache.CHECKOUT / "src" / "repro").is_dir()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", saved)
 
 
 # ---------------------------------------------------------------------------

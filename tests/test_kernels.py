@@ -74,32 +74,27 @@ def test_kernel_dtype_sweep(rng, dtype):
                                rtol=1e-5, atol=1e-5)
 
 
-def _model_words(bb, bt, I, L, F, f_eff):
-    return (bb * F + 3 * bt * I + bt * I * f_eff + 2 * bb * bt * I
-            + bt * L + bb * bt)
-
-
 def test_block_heuristics_fit_budget():
-    from repro.kernels.common import block_heuristics
-    bb, bt = block_heuristics(4096, 1600, 255, 256, 2000)
-    assert bb >= 1 and bt >= 1
-    # the returned blocks actually fit the budget (one-hot modeled at the
-    # per-tree used-feature cap, min(F, I) = 255)
-    words = _model_words(bb, bt, 255, 256, 2000, 255)
-    assert words * 4 <= 12 * 1024 * 1024 or (bb == 1 or bt == 1)
+    from repro.kernels.common import block_heuristics, vmem_words
+    for fused in (False, True):
+        bb, bt = block_heuristics(4096, 1600, 255, 2000, fused=fused)
+        # legal TPU blocks (multiples of 8) that fit the VMEM budget as
+        # the kernel really allocates it (dense [bb, F] tile, F = 2000)
+        assert bb % 8 == 0 and bt % 8 == 0, (bb, bt)
+        assert vmem_words(bb, bt, 255, 2000, fused=fused) * 4 \
+            <= 12 * 1024 * 1024
 
 
 def test_block_heuristics_wide_sparse_sane():
-    """criteo scale (F = 10k): the naive bt*I*F one-hot term drove blocks
-    to (8, 1), starving the MXU; capping the modeled F at the per-tree
-    used-feature bound (<= I) must keep sample blocks large."""
-    from repro.kernels.common import block_heuristics
-    bb, bt = block_heuristics(4096, 1600, 255, 256, 10_000)
+    """criteo scale (F = 10k) dense: the sample tile [bb, F] is the real
+    operand, so bb shrinks until it fits — but the one-hot is chunked over
+    F (never [F, Ip] whole), so sample blocks stay MXU-sized and the tree
+    block keeps its cap."""
+    from repro.kernels.common import block_heuristics, vmem_words
+    bb, bt = block_heuristics(4096, 1600, 255, 10_000, fused=True)
     assert bb >= 64, (bb, bt)
-    assert bt >= 2, (bb, bt)
-    words = _model_words(bb, bt, 255, 256, 10_000, 255)
-    assert words * 4 <= 12 * 1024 * 1024
-    # an explicit per-tree used-feature count tightens the cap further
-    bb2, bt2 = block_heuristics(4096, 1600, 255, 256, 10_000,
-                                used_features=64)
-    assert bb2 >= bb and bt2 >= bt
+    assert bt == 32, (bb, bt)
+    assert vmem_words(bb, bt, 255, 10_000, fused=True) * 4 \
+        <= 12 * 1024 * 1024
+    # the compact CSR tile (F_used = 255) keeps the full 128-row block
+    assert block_heuristics(4096, 1600, 255, 255, fused=True) == (128, 32)

@@ -234,6 +234,25 @@ def test_score_cell_orders_by_work(setup):
     assert host > small
 
 
+def test_tpu_peaks_keyed_by_device_kind(monkeypatch):
+    """A TPU gets the published table of its own device kind; a kind not
+    in the table is an error, never the v5e numbers by default."""
+    import jax
+    from repro.launch import roofline
+
+    class Dev:
+        device_kind = "TPU of an unknown kind"
+
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [Dev()])
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.resolve_peaks("tpu")
+    Dev.device_kind = "TPU v5 lite"
+    peaks = roofline.resolve_peaks("tpu")
+    assert peaks["device_kind"] == "TPU v5 lite"
+    assert peaks["hbm_bandwidth"] == 819e9
+    assert peaks["measured"] is False        # gather/h2d/dispatch assumed
+
+
 def test_calibrated_peaks_measured_once_and_positive():
     from repro.launch import roofline
     p1 = roofline.calibrate_peaks()

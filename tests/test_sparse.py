@@ -10,9 +10,9 @@ The subsystem's contract, as testable invariants:
   * CSR and dense plans agree on predictions for every physical plan,
     and their compiled-plan cache entries never collide;
   * at criteo-scale F the sparse path's traced program contains NO
-    intermediate with a full-F trailing axis — the [BT, I, F] one-hot
-    is gone, not just modeled away (checked on the jaxpr, recursively
-    through the pallas kernel's sub-jaxpr).
+    intermediate with a full-F trailing axis — no full-F sample tile
+    reaches the kernel (checked on the jaxpr, recursively through the
+    pallas kernel's sub-jaxpr).
 """
 
 import numpy as np
@@ -30,6 +30,7 @@ from repro.db.loader import (load_libsvm_csr_external, synth_dataset,
 from repro.db.query import ForestQueryEngine
 from repro.db.sparse import csr_pages_from_dense, densify_csr
 from repro.db.store import TensorBlockStore
+from repro.kernels.common import lane_pad
 from repro.kernels.gather import (csr_block_to_dense, gather_columns,
                                   gather_inverse_map)
 from repro.kernels.ops import FUSED_KERNEL_ALGORITHMS, KERNEL_ALGORITHMS
@@ -261,10 +262,10 @@ def _all_shapes(jaxpr):
 
 
 def test_sparse_path_has_no_full_f_onehot(rng):
-    """F=10k: the dense kernel path materializes a [BT, I, F] predicate
-    one-hot in-kernel; the sparse path (gather prepass + compact forest)
-    must contain NO >=2-D intermediate with a full-F trailing axis
-    anywhere in its traced program."""
+    """F=10k: the dense kernel path stages full-F [BB, F] sample tiles
+    in-kernel; the sparse path (gather prepass + compact forest) must
+    contain NO >=2-D intermediate with a full-F trailing axis anywhere in
+    its traced program."""
     F, B, page_rows = 10_000, 16, 8
     fe, th, dl, lv = random_forest_arrays(rng, T=4, depth=3, F=F, seed=13)
     forest = make_forest(fe, th, lv, default_left=dl, n_features=F)
@@ -289,10 +290,11 @@ def test_sparse_path_has_no_full_f_onehot(rng):
 
     wide = [s for s in sparse_shapes if len(s) >= 2 and s[-1] == F]
     assert not wide, f"full-F intermediates on the sparse path: {wide}"
-    # sanity: the dense path DOES build the [BT, I, F] one-hot
-    assert any(len(s) == 3 and s[-1] == F for s in dense_shapes)
-    # and the compact one-hot is the expected [BT, I, F_used]
-    assert any(len(s) == 3 and s[-1] == f_used for s in sparse_shapes)
+    # sanity: the dense path DOES carry full-F tiles in-kernel
+    assert any(len(s) == 2 and s[-1] == F for s in dense_shapes)
+    # and the compact one-hot is the expected [F_used, Ip] (one tree's
+    # nodes on the lanes)
+    assert (f_used, lane_pad(compact.num_internal)) in sparse_shapes
 
     # parity on the same rows, so the jaxpr claim is about a CORRECT path
     want = np.asarray(dense_path(jnp.asarray(x)))

@@ -18,11 +18,7 @@ The contracts under test:
   * ``TensorBlockStore.drop`` sweeps dependent compiled-plan entries in
     registered engines (the stale-plan-after-re-put regression);
   * ``load_libsvm_csr_external(tier="host")`` parses into host pages
-    with ``transfer_s == 0`` and no device round-trip;
-  * PINNED: the jax-0.4.37 XLA:CPU miscompile of eager ``concatenate``
-    over partially replicated operands, which the executor's host result
-    buffer retired from the hot path.  When a jax bump fixes it, that
-    test fails -> delete it and this note.
+    with ``transfer_s == 0`` and no device round-trip.
 """
 
 import os
@@ -278,6 +274,24 @@ def test_live_device_buffer_count_during_stream():
     np.testing.assert_allclose(out, x.sum(axis=1), rtol=1e-6)
 
 
+def test_pinned_host_probe_never_silent_on_accelerator():
+    """Pinned D2H staging never switches off in silence: a device other
+    than the CPU that lacks the memory kind raises; only the CPU yields
+    None (the plain path)."""
+    from repro.db.executor import _pinned_host_sharding
+
+    class NoPinnedHost:
+        def __init__(self, platform):
+            self.platform = platform
+
+        def memory(self, kind):
+            raise ValueError(f"no memory kind {kind!r}")
+
+    with pytest.raises(ValueError, match="no memory kind"):
+        _pinned_host_sharding(NoPinnedHost("tpu"))
+    assert _pinned_host_sharding(NoPinnedHost("cpu")) is None
+
+
 def test_executor_rejects_deeper_prefetch():
     """The <=2 invariant is a constructor-level contract, not a tuning
     knob: depths that would put 3+ page buffers in flight are refused."""
@@ -419,7 +433,7 @@ def test_libsvm_host_tier_ingest(tmp_path, data_and_forest):
 
 
 # ---------------------------------------------------------------------------
-# multi-device half of the parity grid (+ the pinned miscompile)
+# multi-device half of the parity grid
 # ---------------------------------------------------------------------------
 
 NDEV = len(jax.devices())
@@ -473,10 +487,10 @@ def test_mesh_budget_batch_respects_budget(data_and_forest):
 
 @needs_mesh
 def test_mesh_multibatch_device_tier_needs_no_workaround(data_and_forest):
-    """The retired jax-0.4.37 concatenate workaround's territory: multi-
-    batch device-tier output on a (data, model) mesh.  The executor's
-    host result buffer (per-shard copy + stitch) assembles it correctly
-    without replicating anything first."""
+    """Multi-batch device-tier output on a (data, model) mesh: the
+    executor's host result buffer (per-shard copy + stitch) assembles it
+    bit-identically to one whole-table batch, without replicating
+    anything first."""
     x, xs, forest = data_and_forest
     engine = _engine(_put_tiered(x, xs, mesh=_mesh(2, 4)))
     whole = engine.infer("dense@dev", forest, algorithm=FUSED, plan="rel")
@@ -485,30 +499,3 @@ def test_mesh_multibatch_device_tier_needs_no_workaround(data_and_forest):
     assert multi.scan.batches > 1
     assert np.array_equal(np.asarray(multi.predictions),
                           np.asarray(whole.predictions))
-
-
-@needs_mesh
-@pytest.mark.skipif(jax.__version__ != "0.4.37",
-                    reason="pinned to the jax 0.4.37 miscompile; if this "
-                           "SKIPS after a jax bump, rerun it manually — "
-                           "if it FAILS there, the bug is fixed: delete "
-                           "this test and the executor docstring note")
-def test_jax_0437_partial_replication_concat_miscompile_pinned():
-    """PINNED reproduction of the XLA:CPU bug the old hot-path workaround
-    existed for: eager ``jnp.concatenate`` of PARTIALLY replicated
-    operands sums the replicas — a P('data')-sharded [B] on a
-    (data, model) mesh comes out n_model times too large.  The streaming
-    executor avoids the primitive entirely (host result buffer), so this
-    is the only place the bug is still exercised."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = _mesh(4, 2)                        # n_model = 2
-    sh = NamedSharding(mesh, P("data"))
-    a = jax.device_put(np.arange(8, dtype=np.float32), sh)
-    b = jax.device_put(np.arange(8, 16, dtype=np.float32), sh)
-    got = np.asarray(jnp.concatenate([a, b]))
-    want = np.arange(16, dtype=np.float32)
-    assert np.array_equal(got, 2.0 * want), \
-        "miscompile no longer reproduces — jax was fixed/bumped: delete " \
-        "this test and the retired-workaround notes"
-    # ...while the host gather the executor relies on is NOT affected:
-    assert np.array_equal(np.asarray(a), want[:8])
