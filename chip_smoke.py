@@ -47,6 +47,7 @@ HIGGS_ROWS, HIGGS_F = 11_000_000, 28
 TREES, DEPTH = 500, 8
 CHECK_ROWS = 65_536
 HOST_TIER_BUDGET = 256 << 20      # device budget of phase (c)
+SERVE_REQUESTS = 300              # single-row requests of phase (d)
 TRAIN_ROWS = 1 << 20
 FUSED = "predicated_pallas_fused"
 RTOL, ATOL = 1e-5, 1e-6          # tests/test_multidevice.py
@@ -188,10 +189,11 @@ def phase_host_tier(x: np.ndarray, forest, device_preds: np.ndarray) -> None:
         f"bit_identical=True smoke_wall_s={wall:.2f}")
 
 
-def phase_serve(seed: int, requests: int) -> None:
+def phase_serve(seed: int) -> None:
     from repro.obs import METRICS
     from repro.serve.forest import ForestServeEngine
 
+    requests = SERVE_REQUESTS
     tenants = {"small": random_forest(10, HIGGS_F, seed + 1),
                "large": random_forest(TREES, HIGGS_F, seed + 2)}
     rows = np.random.default_rng(seed + 3).normal(
@@ -291,7 +293,6 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--rows", type=int, default=HIGGS_ROWS,
                     help="higgs rows to generate (a cut of the 11M table)")
-    ap.add_argument("--requests", type=int, default=300)
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     args = ap.parse_args(argv)
 
@@ -319,7 +320,7 @@ def main(argv=None) -> int:
     else:
         preds = phase_scan(x, forest)
         phase_host_tier(x, forest, preds)
-        phase_serve(args.seed, args.requests)
+        phase_serve(args.seed)
         phase_train(x[:TRAIN_ROWS], y[:TRAIN_ROWS])
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0

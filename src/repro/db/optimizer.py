@@ -213,8 +213,10 @@ class CostBasedOptimizer:
     # ------------------------------------------------------------------
     def score_cell(self, cell: _Cell, *, rows: int, trees: int, depth: int,
                    f_used: int, data_nbytes: int, num_pages: int,
-                   page_rows: int, peaks: dict) -> float:
-        """Analytic seconds for one cell over the whole dataset scan."""
+                   page_rows: int, batch_pages: int, peaks: dict) -> float:
+        """Analytic seconds for one cell over the whole dataset scan;
+        ``batch_pages`` is the batch the engine runs when the cell sets
+        none (``ForestQueryEngine.default_batch_pages``)."""
         flops, stream_b, gather_b = _forest_flop_bytes(
             cell.algorithm, rows=rows, trees=trees, depth=depth,
             f_used=f_used)
@@ -248,7 +250,7 @@ class CostBasedOptimizer:
         # dispatch overhead: one per stage per batch (udf: 1 fused
         # stage; rel: cross-product + aggregate + postprocess)
         dispatch = peaks.get("dispatch_s", 5e-6)
-        bp = cell.batch_pages or num_pages
+        bp = cell.batch_pages or batch_pages
         n_batches = max(1, -(-num_pages // max(bp, 1)))
         stages = 1 if cell.plan == "udf" else 3
         cost += dispatch * n_batches * stages
@@ -392,7 +394,10 @@ class CostBasedOptimizer:
                 if c.plan.startswith("rel"):
                     c.n_parts = eng._resolve_n_parts(forest, c.algorithm,
                                                      None)
-                c.predicted_s = self.score_cell(c, **kw)
+                c.predicted_s = self.score_cell(
+                    c, batch_pages=eng.default_batch_pages(ds, forest,
+                                                           c.algorithm),
+                    **kw)
             cells.sort(key=lambda c: c.predicted_s)
             # the executable winner must run on the CURRENT tier; other
             # rungs are scored for the tier recommendation only
@@ -416,9 +421,12 @@ class CostBasedOptimizer:
                             out.append(dataclasses.replace(
                                 cell, n_parts=np_, measured_s=None))
                 if sig[3] != "device":
-                    bp = cell.batch_pages or self._default_batch_pages(ds)
+                    # doubling stops at the predictor's working-set cap
+                    bp = cell.batch_pages or eng.default_batch_pages(
+                        ds, forest, cell.algorithm)
+                    cap = eng.max_batch_pages(ds, forest, cell.algorithm)
                     for bp_ in (max(1, bp // 2),
-                                min(int(ds.num_pages), bp * 2)):
+                                min(int(ds.num_pages), cap, bp * 2)):
                         if bp_ != bp:
                             out.append(dataclasses.replace(
                                 cell, batch_pages=bp_, measured_s=None))
@@ -447,15 +455,6 @@ class CostBasedOptimizer:
                      algorithm=decision.algorithm, plan=decision.plan,
                      tier=decision.tier, source=decision.source)
         return decision
-
-    def _default_batch_pages(self, ds) -> int:
-        """Mirror of the engine's off-device default (half the device
-        budget in pages) used as the hillclimb starting point."""
-        budget = self.engine.store.device_budget_bytes
-        from repro.db.executor import DEFAULT_STREAM_BATCH_BYTES
-        target = budget // 2 if budget else DEFAULT_STREAM_BATCH_BYTES
-        return min(int(ds.num_pages),
-                   max(1, target // max(int(ds.page_nbytes), 1)))
 
     # ------------------------------------------------------------------
     # row-batch decisions (serving plane: register_model)
@@ -498,7 +497,7 @@ class CostBasedOptimizer:
                     c, rows=B, trees=int(forest.num_trees),
                     depth=int(forest.depth), f_used=F,
                     data_nbytes=B * F * 4, num_pages=1, page_rows=B,
-                    peaks=peaks)
+                    batch_pages=1, peaks=peaks)
             cells.sort(key=lambda c: c.predicted_s)
             uncertain = [c for c in cells if c.predicted_s
                          <= cells[0].predicted_s * self.uncertainty_band]

@@ -240,11 +240,12 @@ def predict_row_bytes(algorithm: str, forest: Forest, features: int) -> int:
     quickscorer    [rows, T, I, W] bit-vector masks
     other jnp      [rows, T] index, gathered operands and scores
     """
+    from repro.kernels.ops import FUSED_KERNEL_ALGORITHMS, KERNEL_ALGORITHMS
     T, I, L = forest.num_trees, forest.num_internal, forest.num_leaves
     row = 2 * 4 * features
-    if algorithm.endswith("_pallas_fused"):
+    if algorithm in FUSED_KERNEL_ALGORITHMS:
         return row + 4 * (T + 4)
-    if algorithm.endswith("_pallas"):
+    if algorithm in KERNEL_ALGORITHMS:
         return row + 2 * 4 * T
     if algorithm == "hummingbird":
         return row + 2 * 4 * T * (I + L)
@@ -586,6 +587,42 @@ class ForestQueryEngine:
         return max(1, int(n_parts))
 
     # ------------------------------------------------------------------
+    # scan batch sizing
+    # ------------------------------------------------------------------
+    def max_batch_pages(self, ds, forest: Forest, algorithm: str) -> int:
+        """Largest scan batch, in pages, whose predictor intermediates
+        fit ``PREDICT_BATCH_BYTES`` (a resident table scored in one batch
+        would hold [rows, T]-sized intermediates for all of its rows).
+        Rounded down to data-axis units; floor one page per device."""
+        unit = max(1, self.fplan.n_data)
+        features = ds.num_features \
+            if getattr(ds, "storage_format", "dense") == "dense" \
+            else min(ds.num_features, forest.num_trees * forest.num_internal)
+        page_cost = ds.page_rows * predict_row_bytes(algorithm, forest,
+                                                     features)
+        fit = PREDICT_BATCH_BYTES // max(page_cost, 1)
+        return max(unit, fit // unit * unit)
+
+    def default_batch_pages(self, ds, forest: Forest, algorithm: str) -> int:
+        """The scan batch ``infer`` runs when no ``batch_pages`` is given
+        (the optimizer's hillclimb start and cost-model batch too): the
+        whole table on the device tier; off it (host AND disk) half the
+        device budget, so two in-flight page buffers together fit it, or
+        a fixed footprint when no budget is set — an explicit off-device
+        ingest must still stream, never whole-dataset device_put.  Sized
+        in data-axis units, rounding DOWN, so the mesh divisibility
+        round-up cannot push the pair past the budget.  On every tier
+        the batch is capped at ``max_batch_pages``."""
+        batch_pages = ds.num_pages
+        if getattr(ds, "tier", "device") != "device":
+            budget = self.store.device_budget_bytes
+            target = budget // 2 if budget else DEFAULT_STREAM_BATCH_BYTES
+            unit = max(1, self.fplan.n_data)
+            fit = target // max(ds.page_nbytes, 1)
+            batch_pages = min(ds.num_pages, max(unit, fit // unit * unit))
+        return min(batch_pages, self.max_batch_pages(ds, forest, algorithm))
+
+    # ------------------------------------------------------------------
     # entry point
     # ------------------------------------------------------------------
     def infer(self, dataset: str, forest: Forest, **kw) -> QueryResult:
@@ -841,32 +878,7 @@ class ForestQueryEngine:
         deadline = Deadline(deadline_s, start=t_query0) \
             if deadline_s is not None else None
         if batch_pages is None:
-            batch_pages = ds.num_pages
-            if tier != "device":
-                # out-of-core default (host AND disk tiers): a batch is
-                # half the device budget (two in-flight page buffers
-                # together fit it), or a fixed footprint when no budget
-                # is set — an explicit off-device ingest must still
-                # stream, never whole-dataset device_put.  Sized in
-                # data-axis units, rounding DOWN, so the mesh
-                # divisibility round-up below cannot push the pair past
-                # the budget (floor: one page per device).
-                budget = self.store.device_budget_bytes
-                target = budget // 2 if budget else DEFAULT_STREAM_BATCH_BYTES
-                unit = max(1, self.fplan.n_data)
-                fit = target // max(ds.page_nbytes, 1)
-                batch_pages = min(ds.num_pages,
-                                  max(unit, fit // unit * unit))
-            # every tier: the predictor's device working set bounds the
-            # batch (a resident table scored in one batch would hold
-            # [rows, T]-sized intermediates for all of its rows)
-            unit = max(1, self.fplan.n_data)
-            features = ds.num_features if fmt == "dense" else min(
-                ds.num_features, forest.num_trees * forest.num_internal)
-            page_cost = ds.page_rows * predict_row_bytes(
-                algorithm, forest, features)
-            fit = PREDICT_BATCH_BYTES // max(page_cost, 1)
-            batch_pages = min(batch_pages, max(unit, fit // unit * unit))
+            batch_pages = self.default_batch_pages(ds, forest, algorithm)
         if self.fplan.n_data > 1:
             # shard_map needs page batches that divide evenly over the
             # data axis; num_pages itself is a data-axis multiple (the

@@ -125,6 +125,42 @@ def test_decision_persists_in_store_catalog(setup):
     assert entry["plan"] in ("udf", "rel+reuse")
 
 
+def test_host_tier_auto_never_exceeds_predictor_cap(setup, monkeypatch):
+    """Regression: the hillclimb passes ``batch_pages`` explicitly, which
+    skips the engine's default cap — doubling from the default must stop
+    at ``max_batch_pages``, and so must the decision it persists."""
+    from repro.db import query
+    forest, x = setup
+    store = TensorBlockStore(default_page_rows=64)
+    ds = store.put("ds", x, tier="host")
+    engine = ForestQueryEngine(store, reuse_cache=ModelReuseCache())
+    engine.optimizer = CostBasedOptimizer(
+        engine, measure_budget_s=30.0, max_measurements=8, probe_iters=1,
+        uncertainty_band=1e9)
+    page_cost = 64 * query.predict_row_bytes("predicated", forest,
+                                             x.shape[1])
+    monkeypatch.setattr(query, "PREDICT_BATCH_BYTES", 2 * page_cost)
+    cap = engine.max_batch_pages(ds, forest, "predicated")
+    assert cap == 2 < ds.num_pages
+    assert engine.default_batch_pages(ds, forest, "predicated") == cap
+    ran = []
+    infer = engine._infer
+
+    def spy(*a, **kw):
+        res = infer(*a, **kw)
+        ran.append((kw.get("batch_pages"), res.scan.batch_pages))
+        return res
+
+    monkeypatch.setattr(engine, "_infer", spy)
+    res = engine.infer("ds", forest, plan="auto", algorithm="predicated")
+    assert any(asked is not None for asked, _ in ran), \
+        "the hillclimb never probed a batch size"
+    assert max(got for _, got in ran) <= cap, ran
+    assert res.decision.batch_pages is None \
+        or res.decision.batch_pages <= cap
+    assert res.scan.batch_pages <= cap
+
+
 # ---------------------------------------------------------------------------
 # invalidation: decisions are swept exactly like compiled plans
 # ---------------------------------------------------------------------------
@@ -222,7 +258,7 @@ def test_score_cell_orders_by_work(setup):
     from repro.launch.roofline import resolve_peaks
     peaks = resolve_peaks()
     kw = dict(trees=100, depth=8, f_used=32, data_nbytes=1 << 20,
-              num_pages=16, page_rows=256, peaks=peaks)
+              num_pages=16, page_rows=256, batch_pages=16, peaks=peaks)
     small = opt.score_cell(_Cell("predicated", "udf", "device"),
                            rows=1024, **kw)
     big = opt.score_cell(_Cell("predicated", "udf", "device"),
