@@ -8,9 +8,8 @@ imports are warm):
     repo root, collects EVERY dict key that occurs anywhere in the
     payload (top-level, ``env``, and per-record fields alike), and
     fails if any key is not mentioned — in backticks — in the doc.
-    ``BENCH_obs.json`` is held against ``docs/observability.md``
-    instead: the observability plane's fields belong with its span
-    taxonomy, not in the generic benchmark contract.
+    A file named in ``DOC_OVERRIDES`` is held against its plane's own
+    doc instead.
   * ``docs/observability.md`` is the contract for the observability
     plane itself: every span / event / metric name the instrumentation
     can export (the catalog in ``src/repro/obs/names.py`` — imported
@@ -42,8 +41,7 @@ TRAIN_DOC = ROOT / "docs" / "training.md"
 
 #: bench files whose field contract lives in a doc other than
 #: docs/benchmarks.md
-DOC_OVERRIDES = {"BENCH_obs.json": OBS_DOC,
-                 "BENCH_serve.json": SERVE_DOC,
+DOC_OVERRIDES = {"BENCH_serve.json": SERVE_DOC,
                  "BENCH_optimizer.json": OPTIMIZER_DOC,
                  "BENCH_train.json": TRAIN_DOC}
 

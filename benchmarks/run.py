@@ -95,21 +95,6 @@ def main() -> None:
             f"vs_baseline={r['baseline_wall_s']}s"))
     print(f"# fault trajectory -> {fault_path}")
 
-    from benchmarks import bench_obs
-    print("\n## Observability plane: armed-tracing overhead")
-    orows, obs_records = bench_obs.run(
-        trees=trees[0] if args.fast else trees[-1],
-        scale=min(scale, 0.25), iters=3 if args.fast else 5)
-    C.print_rows(orows)
-    obs_path = bench_obs.write_obs_json(obs_records)
-    for r in obs_records:
-        summary.append(C.csv_line(
-            f"obs/{r['scenario']}", r["traced_wall_s"],
-            f"overhead={r['overhead_fraction']:+.1%} "
-            f"spans={r['spans_recorded']} "
-            f"cross_thread={r['cross_thread_spans']}"))
-    print(f"# obs trajectory -> {obs_path}")
-
     from benchmarks import bench_wide_sparse
     print("\n## Tab7-9: wide/sparse datasets (bosch, epsilon, criteo)")
     rows = bench_wide_sparse.run(trees=trees, scale=scale)

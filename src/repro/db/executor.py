@@ -177,7 +177,8 @@ class ScanStats:
     transfer_issue_s: float = 0.0    # time spent ISSUING device_puts
     transfer_wait_s: float = 0.0     # EXPOSED wait for pages to be ready
     #                                  (what double-buffering hides)
-    compute_s: float = 0.0           # kernel-stage wall time
+    compute_s: float = 0.0           # host time dispatching each batch's
+    #                                  stages: an enqueue, not device time
     drain_s: float = 0.0             # device->host result-buffer writes
     #                                  (on the WORKER thread when async)
     drain_wait_s: float = 0.0        # compute-thread time BLOCKED on the
@@ -772,12 +773,14 @@ class StreamingScanExecutor:
                 # worker left behind.
                 if async_drain:
                     t0 = time.perf_counter()
-                    if drain_active:
-                        put_drain(None)   # sentinel: no more batches
-                    worker.join(timeout=5.0)
-                    if sink.dead:
-                        stats.degraded_to_sync = True
-                    sink.drain_pending(drain_q)
+                    # the query's exposed tail: the last batches' drain
+                    with TRACER.span("scan.drain_join"):
+                        if drain_active:
+                            put_drain(None)   # sentinel: no more batches
+                        worker.join(timeout=5.0)
+                        if sink.dead:
+                            stats.degraded_to_sync = True
+                        sink.drain_pending(drain_q)
                     stats.drain_wait_s += time.perf_counter() - t0
                 scan_span.set(batches=stats.batches,
                               bytes_streamed=stats.bytes_streamed,

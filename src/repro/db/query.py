@@ -709,86 +709,97 @@ class ForestQueryEngine:
         (model, batch signature, mesh) — the serve plane resolves this
         once at ``register_model`` instead of per call).
         """
-        if plan == "auto" or algorithm == "auto":
-            dec = self.optimizer.decide_rows(
-                forest, int(getattr(x, "shape", (len(x),))[0]),
-                model_id=model_id,
-                algorithms=None if algorithm == "auto" else (algorithm,),
-                plans=None if plan == "auto" else (plan,))
-            algorithm, plan = dec.algorithm, dec.plan
-            if n_parts is None:
-                n_parts = dec.n_parts
-        if plan not in ("udf", "rel+reuse"):
-            raise ValueError(
-                f"infer_rows serves cached plans only (udf / rel+reuse), "
-                f"got {plan!r}")
-        t0 = time.perf_counter()
-        x = jnp.asarray(x, jnp.float32)
-        if x.ndim != 2:
-            raise ValueError(f"expected [B, F] rows, got shape {x.shape}")
-        B, F = int(x.shape[0]), int(x.shape[1])
-        if self.fplan.n_data > 1 and B % self.fplan.n_data:
-            raise ValueError(
-                f"row batch {B} must divide the mesh data axis "
-                f"({self.fplan.n_data}) — pick bucket sizes that are "
-                f"axis multiples")
-        sharding = self.store.data_sharding()
-        if sharding is not None:
-            x = jax.device_put(x, sharding)
-        mid = self._model_key(forest, model_id)
-        mesh_id = mesh_signature(self.mesh)
-        batch_sig = (B, F)
-
-        with TRACER.span("query.infer_rows", plan=plan,
-                         algorithm=algorithm, batch_rows=B) as sp:
-            if plan == "udf":
-                pkey = ("udf-row-plan", mid, ROW_PLAN_DATASET, algorithm,
-                        "dense", batch_sig, mesh_id)
-
-                def build() -> CompiledQueryPlan:
-                    with TRACER.span("plan.build", plan="udf-rows",
-                                     algorithm=algorithm):
-                        fp, true_T = pad_trees(forest, 1)
-                        stages = split_into_stages(
-                            self._udf_ops(fp, algorithm, true_T))
-                        return CompiledQueryPlan(stages=stages,
-                                                 num_stages=len(stages))
-            else:
-                n_parts = self._resolve_n_parts(forest, algorithm, n_parts)
-                mkey = (mid, algorithm, n_parts, mesh_id, "dense")
-                mat = self.cache.get_or_build(
-                    mkey, lambda: self._partition_model(
-                        forest, algorithm, n_parts))
-                pkey = ("rel-row-plan", mid, ROW_PLAN_DATASET, algorithm,
-                        n_parts, "dense", batch_sig, mesh_id, id(mat))
-
-                def build() -> CompiledQueryPlan:
-                    with TRACER.span("plan.build", plan="rel-rows",
-                                     algorithm=algorithm):
-                        stages = split_into_stages(
-                            self._rel_ops(mat, algorithm, n_parts))
-                        return CompiledQueryPlan(stages=stages,
-                                                 num_stages=len(stages) + 1,
-                                                 mat=mat)
-
-            before = self.plan_cache.stats.hits
-            qplan = self.plan_cache.get_or_build(pkey, build)
-            plan_hit = self.plan_cache.stats.hits > before
-            METRICS.counter("plan.cache_hits" if plan_hit
-                            else "plan.cache_misses").inc()
-            TRACER.event("plan.cache", hit=plan_hit, plan=f"{plan}-rows")
-
-            state, _ = run_stages(qplan.stages, {"x": x})
-            preds = state["pred"]
-            rows_scored = B
-            if row_mask is not None:
-                mask = np.asarray(row_mask, bool)
-                if mask.shape != (B,):
+        with TRACER.span("query.infer_rows") as sp:
+            if plan == "auto" or algorithm == "auto":
+                dec = self.optimizer.decide_rows(
+                    forest, int(getattr(x, "shape", (len(x),))[0]),
+                    model_id=model_id,
+                    algorithms=None if algorithm == "auto"
+                    else (algorithm,),
+                    plans=None if plan == "auto" else (plan,))
+                algorithm, plan = dec.algorithm, dec.plan
+                if n_parts is None:
+                    n_parts = dec.n_parts
+            if plan not in ("udf", "rel+reuse"):
+                raise ValueError(
+                    f"infer_rows serves cached plans only (udf / "
+                    f"rel+reuse), got {plan!r}")
+            t0 = time.perf_counter()
+            # every host->device transfer of the batch: rows and mask
+            with TRACER.span("query.rows_upload"):
+                x = jnp.asarray(x, jnp.float32)
+                if x.ndim != 2:
                     raise ValueError(
-                        f"row_mask shape {mask.shape} != ({B},)")
-                rows_scored = int(mask.sum())
-                # padding rows never leak: their predictions are NaN
-                preds = jnp.where(jnp.asarray(mask), preds, jnp.nan)
+                        f"expected [B, F] rows, got shape {x.shape}")
+                B, F = int(x.shape[0]), int(x.shape[1])
+                if self.fplan.n_data > 1 and B % self.fplan.n_data:
+                    raise ValueError(
+                        f"row batch {B} must divide the mesh data axis "
+                        f"({self.fplan.n_data}) — pick bucket sizes that "
+                        f"are axis multiples")
+                sharding = self.store.data_sharding()
+                if sharding is not None:
+                    x = jax.device_put(x, sharding)
+                rows_scored = B
+                mask = None
+                if row_mask is not None:
+                    mask = np.asarray(row_mask, bool)
+                    if mask.shape != (B,):
+                        raise ValueError(
+                            f"row_mask shape {mask.shape} != ({B},)")
+                    rows_scored = int(mask.sum())
+                    mask = jnp.asarray(mask)
+            sp.set(plan=plan, algorithm=algorithm, batch_rows=B)
+            mid = self._model_key(forest, model_id)
+            mesh_id = mesh_signature(self.mesh)
+            batch_sig = (B, F)
+
+            with TRACER.span("query.rows_dispatch"):
+                if plan == "udf":
+                    pkey = ("udf-row-plan", mid, ROW_PLAN_DATASET,
+                            algorithm, "dense", batch_sig, mesh_id)
+
+                    def build() -> CompiledQueryPlan:
+                        with TRACER.span("plan.build", plan="udf-rows",
+                                         algorithm=algorithm):
+                            fp, true_T = pad_trees(forest, 1)
+                            stages = split_into_stages(
+                                self._udf_ops(fp, algorithm, true_T))
+                            return CompiledQueryPlan(
+                                stages=stages, num_stages=len(stages))
+                else:
+                    n_parts = self._resolve_n_parts(forest, algorithm,
+                                                    n_parts)
+                    mkey = (mid, algorithm, n_parts, mesh_id, "dense")
+                    mat = self.cache.get_or_build(
+                        mkey, lambda: self._partition_model(
+                            forest, algorithm, n_parts))
+                    pkey = ("rel-row-plan", mid, ROW_PLAN_DATASET,
+                            algorithm, n_parts, "dense", batch_sig,
+                            mesh_id, id(mat))
+
+                    def build() -> CompiledQueryPlan:
+                        with TRACER.span("plan.build", plan="rel-rows",
+                                         algorithm=algorithm):
+                            stages = split_into_stages(
+                                self._rel_ops(mat, algorithm, n_parts))
+                            return CompiledQueryPlan(
+                                stages=stages,
+                                num_stages=len(stages) + 1, mat=mat)
+
+                before = self.plan_cache.stats.hits
+                qplan = self.plan_cache.get_or_build(pkey, build)
+                plan_hit = self.plan_cache.stats.hits > before
+                METRICS.counter("plan.cache_hits" if plan_hit
+                                else "plan.cache_misses").inc()
+                TRACER.event("plan.cache", hit=plan_hit,
+                             plan=f"{plan}-rows")
+
+                state, _ = run_stages(qplan.stages, {"x": x})
+                preds = state["pred"]
+                if mask is not None:
+                    # padding rows never leak: their predictions are NaN
+                    preds = jnp.where(mask, preds, jnp.nan)
             sp.set(reuse_hit=plan_hit, rows=rows_scored)
 
         return RowBatchResult(
@@ -1014,7 +1025,8 @@ class ForestQueryEngine:
             min_batch_pages=max(1, self.fplan.n_data))
         out_np, batch_reports, scan = executor.execute(ds, batch_pages)
         reports: list[StageReport] = list(prefix_reports) + batch_reports
-        predictions = jnp.asarray(out_np)
+        with TRACER.span("query.result_upload"):
+            predictions = jnp.asarray(out_np)
 
         degraded = None
         if scan.deadline_hit:

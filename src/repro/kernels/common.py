@@ -138,8 +138,12 @@ def _accumulate_trees(tree_score, x_ref, feat_ref, thr_ref, dl_ref,
 
 
 def forest_call(tree_score, x, feature, threshold, default_left, leaf_value,
-                aux=(), *, block_b, block_t, interpret, fused):
+                aux=(), *, name, block_b, block_t, interpret, fused):
     """The one ``pallas_call`` behind every forest kernel.
+
+    ``name`` names the kernel in compiled code and in profiler traces
+    (``forest_<kind>_fused`` / ``forest_<kind>``): one per tree-score
+    variant, never varying with shape or block size.
 
     x [B, F] and the tree arrays [T, Ip] (``default_left`` int32) must
     already be padded to block multiples and the lane width
@@ -196,6 +200,7 @@ def forest_call(tree_score, x, feature, threshold, default_left, leaf_value,
         scratch_shapes=[pltpu.VMEM((block_t, Ip), jnp.float32)
                         for n in narrow if n],
         interpret=interpret,
+        name=name,
     )(x, feature, threshold, default_left, leaf_value, *aux)
 
 

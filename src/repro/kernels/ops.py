@@ -252,8 +252,10 @@ def _run(kind: str, forest: Forest, x: jax.Array, *, fused: bool,
     xp, fe, th, dl, lv, block_b, block_t, interpret = _prepared(
         forest, x, block_b, block_t, interpret, fused=fused,
         tree_dtype=tree_dtype)
-    out = forest_call(score, xp, fe, th, dl, lv, aux, block_b=block_b,
-                      block_t=block_t, interpret=interpret, fused=fused)
+    out = forest_call(score, xp, fe, th, dl, lv, aux,
+                      name=f"forest_{kind}" + ("_fused" if fused else ""),
+                      block_b=block_b, block_t=block_t, interpret=interpret,
+                      fused=fused)
     if fused:
         return out[:B, 0]
     # [T/BT, Bp, BT] tiles -> [Bp, Tp] per-tree scores

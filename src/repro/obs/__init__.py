@@ -6,8 +6,9 @@ nested across threads and exportable as Chrome trace-event JSON
 (Perfetto); ``METRICS`` is the process-global counter/histogram
 registry the scattered per-layer accounting rolls up into.  Span /
 event / metric names are cataloged in ``obs/names.py`` and documented
-(CI-enforced) in ``docs/observability.md``; armed overhead is measured
-by ``benchmarks/bench_obs.py`` (``BENCH_obs.json``, <5% bound).
+(CI-enforced) in ``docs/observability.md``.  While armed, every span
+also enters a ``jax.profiler.TraceAnnotation`` of its name, so the
+engine's phases share the profiler trace's clock with the device.
 """
 
 from repro.obs.metrics import (DEFAULT_LATENCY_BOUNDS_S, METRICS, Counter,

@@ -19,9 +19,9 @@ Design rules, in the same spirit as ``db/faults.py``:
     here can ever end up traced into a jitted stage.
   * CHEAP WHEN IDLE — a counter is one lock + one int add; the
     registry has no background thread, no export loop, no string
-    formatting on the hot path.  The measured cost of the fully armed
-    plane is ``BENCH_obs.json`` (<5% bound, same gate discipline as
-    ``BENCH_faults.json``).
+    formatting on the hot path.  The cost of the fully armed plane
+    is measured on the chip (traced against untraced benchmark runs,
+    ``docs/observability.md``).
   * FIXED BUCKETS — histograms never allocate per-sample; percentile
     queries interpolate inside the landing bucket, clamped to the
     observed min/max, which keeps p50/p99 honest at bucket resolution

@@ -20,6 +20,9 @@ SPAN_NAMES = (
     # query engine (db/query.py)
     "query.infer",
     "query.infer_rows",
+    "query.rows_upload",
+    "query.rows_dispatch",
+    "query.result_upload",
     "plan.build",
     "plan.partition",
     "query.write",
@@ -32,6 +35,7 @@ SPAN_NAMES = (
     "scan.compute",
     "scan.drain_submit",
     "scan.drain_write",
+    "scan.drain_join",
     # tensor-block store (db/store.py)
     "store.put",
     "store.put_sparse",
@@ -45,6 +49,8 @@ SPAN_NAMES = (
     "serve.execute",
     "serve.tick",
     "serve.coalesce",
+    "serve.result_wait",
+    "serve.handback",
     # cost-based optimizer (db/optimizer.py)
     "optimizer.decide",
     "optimizer.autotune",

@@ -14,14 +14,20 @@ The contract that keeps this off the hot path:
     ``tracer.span(...)`` returns the shared ``NULL_SPAN`` singleton
     whose enter/exit/event/set are no-ops (asserted by
     ``tests/test_obs.py``); arming is ``tracer.enable()``.
-  * ARMED overhead is measured, not promised: ``benchmarks/bench_obs.py``
-    runs the fused streamed scan traced vs untraced and RAISES past the
-    5% bound (``BENCH_obs.json``), the same gate discipline as
-    ``BENCH_faults.json``.
+  * ARMED overhead is measured on the chip: traced against untraced
+    runs of the benchmark's cells (``docs/observability.md``).
   * Monotonic clocks only (``time.perf_counter_ns``) — span math never
     sees wall-clock adjustments.
-  * Stdlib only — nothing here can be traced into a jitted stage, and
-    the CI docs gate can import the module without jax.
+  * ONE CLOCK WITH THE DEVICE — while armed, each span also enters a
+    ``jax.profiler.TraceAnnotation`` named by the span's catalogue name
+    (no attrs: TraceMe would fold them into the event name), entered and
+    left on the span's own thread.  Under ``jax.profiler`` the engine's
+    phases then land on the trace's ``/host:CPU`` plane, on the clock of
+    the device's ``XLA Ops``.
+  * Stdlib only at import — ``jax.profiler`` is resolved lazily, once,
+    by ``enable()``; without JAX spans simply do not annotate.  Nothing
+    here can be traced into a jitted stage, and the CI docs gate can
+    import the module without jax.
 
 Export: ``tracer.export_chrome(path)`` writes Chrome trace-event JSON
 (the ``traceEvents`` array format) loadable in Perfetto / chrome://
@@ -41,6 +47,7 @@ documented (CI-enforced) in ``docs/observability.md``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import json
 import threading
@@ -71,7 +78,7 @@ class Span:
 
     __slots__ = ("name", "attrs", "span_id", "parent_id", "start_ns",
                  "end_ns", "tid", "thread_name", "events", "_tracer",
-                 "_parent")
+                 "_parent", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str,
                  parent: "Span | None" = None, attrs: dict | None = None):
@@ -86,6 +93,7 @@ class Span:
         self.thread_name = ""
         self.events: list[SpanEvent] = []
         self._tracer = tracer
+        self._annotation = None
 
     # -- context manager ----------------------------------------------------
     def __enter__(self) -> "Span":
@@ -98,6 +106,10 @@ class Span:
         if isinstance(parent, Span):
             self.parent_id = parent.span_id
         self._tracer._push(self)
+        annotate = self._tracer._annotate
+        if annotate is not None:
+            self._annotation = annotate(self.name)
+            self._annotation.__enter__()
         self.start_ns = time.perf_counter_ns()
         return self
 
@@ -105,6 +117,9 @@ class Span:
         self.end_ns = time.perf_counter_ns()
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         self._tracer._pop(self)
         self._tracer._finished.append(self)
 
@@ -162,7 +177,8 @@ class TraceSummary:
 
     ``phase_s`` sums span wall seconds BY SPAN NAME over the query's
     span tree (so ``phase_s["scan.compute"]`` is comparable with
-    ``ScanStats.compute_s`` — both clock the same code region);
+    ``ScanStats.compute_s`` — both clock the same code region: the
+    host's dispatch of a batch's stages, not the device's work);
     ``span_counts`` / ``event_counts`` count spans and events by name;
     ``counters`` holds the process-global ``METRICS`` counter deltas
     that accrued while the query ran.
@@ -179,6 +195,17 @@ class TraceSummary:
     def phase(self, name: str) -> float:
         """Total seconds of spans named ``name`` (0.0 when absent)."""
         return self.phase_s.get(name, 0.0)
+
+
+@functools.cache
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use; None
+    where JAX is not installed."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
 
 
 def _jsonable(v):
@@ -199,6 +226,9 @@ class Tracer:
 
     def __init__(self):
         self.enabled = False
+        #: ``jax.profiler.TraceAnnotation`` once ``enable()`` found JAX;
+        #: None before that, or without JAX
+        self._annotate = None
         self._ids = itertools.count(1)
         self._finished: deque[Span] = deque()
         self._orphan_events: deque[SpanEvent] = deque()
@@ -207,6 +237,7 @@ class Tracer:
 
     # -- arming -------------------------------------------------------------
     def enable(self) -> "Tracer":
+        self._annotate = _profiler_annotation()
         self.enabled = True
         return self
 

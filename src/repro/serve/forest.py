@@ -89,7 +89,7 @@ class ForestRequest:
     #                                    contract; docs/reliability.md)
     shed: bool = False
     submitted_at: float = 0.0
-    admitted_at: float = 0.0           # coalesced into a tick
+    admitted_at: float = 0.0           # taken by the flush that served it
     finished_at: float = 0.0
     predictions: np.ndarray | None = None   # [k] on completion
     error: BaseException | None = None
@@ -405,13 +405,16 @@ class ForestServeEngine:
                 return b
         return self.buckets[-1]
 
-    def _flush(self, m: ServedModel, now: float) -> int:
+    def _flush(self, m: ServedModel) -> int:
         """Coalesce one padded batch for ``m`` and serve it through
         ``infer_rows``.  Returns rows served (0 if the queue was
-        empty)."""
+        empty).  Each request is admitted when ``_select`` hands this
+        flush its batch: a tick loop under a backlog flushes several
+        batches, each with its own admission time."""
         batch = self._select(m)
         if not batch:
             return 0
+        admitted = time.perf_counter()
         n = sum(r.num_rows for r in batch)
         bucket = self._bucket(n)
         with TRACER.span("serve.tick", model=m.name, requests=len(batch),
@@ -425,9 +428,9 @@ class ForestServeEngine:
                     x[off:off + req.num_rows] = req.rows
                     mask[off:off + req.num_rows] = True
                     off += req.num_rows
-                    req.admitted_at = now
+                    req.admitted_at = admitted
                     m.metrics.histogram("serve.queue_wait_s").record(
-                        now - req.submitted_at)
+                        admitted - req.submitted_at)
             for reg in (m.metrics, self.metrics):
                 reg.counter("serve.ticks").inc()
                 reg.counter("serve.padding_rows").inc(bucket - n)
@@ -448,16 +451,18 @@ class ForestServeEngine:
             m.metrics.counter("serve.plan_hits" if res.plan_reuse_hit
                               else "serve.plan_misses").inc()
             sp.set(plan_hit=res.plan_reuse_hit)
-            out = np.asarray(res.predictions)
+            with TRACER.span("serve.result_wait"):
+                out = np.asarray(res.predictions)
             done_at = time.perf_counter()
-            off = 0
-            for req in batch:
-                req.predictions = out[off:off + req.num_rows].copy()
-                off += req.num_rows
-                req.finished_at = done_at
-                m.metrics.histogram("serve.e2e_latency_s").record(
-                    done_at - req.submitted_at)
-                req.done.set()
+            with TRACER.span("serve.handback"):
+                off = 0
+                for req in batch:
+                    req.predictions = out[off:off + req.num_rows].copy()
+                    off += req.num_rows
+                    req.finished_at = done_at
+                    m.metrics.histogram("serve.e2e_latency_s").record(
+                        done_at - req.submitted_at)
+                    req.done.set()
         return n
 
     def tick(self, now: float | None = None, force: bool = False) -> int:
@@ -473,7 +478,7 @@ class ForestServeEngine:
         for m in models:
             self._shed_timed_out(m, now)
             while m.pending and (force or self._due(m, now)):
-                served += self._flush(m, now)
+                served += self._flush(m)
         return served
 
     def drain(self, max_ticks: int = 10_000) -> int:

@@ -15,14 +15,19 @@ The contracts under test, per ``docs/observability.md``:
     drain-death ladder produces its ``degrade.sync_drain``.
   * THE EXPORT IS VALID — Chrome trace-event JSON round-trips, every
     span row carries ``ph``/``ts``/``dur``/``tid``, parent chains
-    resolve (validated by ``benchmarks.bench_obs.validate_chrome_trace``,
-    the same checker the CI obs-smoke job runs).
+    resolve (``validate_chrome_trace`` below).
   * SUMMARIES AGREE WITH STATS — ``trace.phase("scan.compute")`` clocks
     the same region as ``ScanStats.compute_s``.
+  * ONE CLOCK WITH THE DEVICE — under ``jax.profiler`` an armed tracer's
+    spans appear on the trace's ``/host:CPU`` plane by their catalogue
+    names alone, nested as the tracer nests them; a disarmed one leaves
+    nothing there.
 """
 
+import glob
 import json
-import pathlib
+import os
+import subprocess
 import sys
 import threading
 
@@ -37,9 +42,6 @@ from repro.db.query import ForestQueryEngine
 from repro.db.store import TensorBlockStore
 from repro.obs import (METRICS, NULL_SPAN, TRACER, Counter, Histogram,
                        MetricsRegistry)
-
-sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
-from benchmarks.bench_obs import validate_chrome_trace  # noqa: E402
 
 N, F, T, PAGE = 384, 16, 24, 32
 FUSED = "predicated_pallas_fused"
@@ -70,6 +72,44 @@ def env():
     engine = ForestQueryEngine(store, reuse_cache=ModelReuseCache(),
                                plan_cache=ModelReuseCache())
     return engine, forest, x
+
+
+def validate_chrome_trace(payload: dict) -> dict:
+    """Structural validation of an exported Chrome trace: round-trips
+    through json, checks the trace-event contract on every row, and
+    resolves parent chains.  Returns summary counts; raises on any
+    violation."""
+    data = json.loads(json.dumps(payload))        # serializability
+    events = data["traceEvents"]
+    if not events:
+        raise RuntimeError("exported trace is empty")
+    spans = {}
+    for ev in events:
+        if not isinstance(ev.get("name"), str) or "ph" not in ev:
+            raise RuntimeError(f"malformed trace event: {ev}")
+        if ev["ph"] == "X":
+            for field in ("ts", "dur", "tid", "pid"):
+                if not isinstance(ev.get(field), (int, float)):
+                    raise RuntimeError(
+                        f"span {ev['name']!r} missing numeric {field}")
+            spans[ev["args"]["span_id"]] = ev
+    nested = cross_thread = 0
+    for ev in spans.values():
+        pid = ev["args"].get("parent_id")
+        if pid is None:
+            continue
+        parent = spans.get(pid)
+        if parent is None:
+            raise RuntimeError(
+                f"span {ev['name']!r} parent_id {pid} unresolved")
+        nested += 1
+        if parent["tid"] != ev["tid"]:
+            cross_thread += 1
+    if nested == 0:
+        raise RuntimeError("no nested spans in exported trace")
+    threads = {ev["tid"] for ev in events if ev["ph"] == "X"}
+    return {"events": len(events), "spans": len(spans), "nested": nested,
+            "cross_thread": cross_thread, "threads": len(threads)}
 
 
 def traced_infer(engine, forest, name, **kw):
@@ -341,3 +381,103 @@ def test_store_and_loader_spans(env):
     move = [s for s in TRACER.finished() if s.name == "store.move"][0]
     assert move.attrs["src"] == "device" and move.attrs["dst"] == "host"
     store.drop("obs-put")
+
+
+# ---------------------------------------------------------------------------
+# one clock with the device: spans as profiler annotations
+# ---------------------------------------------------------------------------
+
+#: where each phase span nests, by the innermost engine span around it
+PHASE_PARENTS = {
+    "query.rows_upload": "query.infer_rows",
+    "query.rows_dispatch": "query.infer_rows",
+    "query.infer_rows": "serve.tick",
+    "serve.result_wait": "serve.tick",
+    "serve.handback": "serve.tick",
+    "scan.drain_join": "scan.execute",
+    "query.result_upload": "query.infer",
+}
+
+
+def _engine_name(name: str) -> bool:
+    from repro.obs import SPAN_NAMES, SPAN_PREFIXES
+    base = name.split("#")[0]
+    return base in SPAN_NAMES or base.startswith(SPAN_PREFIXES)
+
+
+def _profiled(env, tmp_path, armed: bool):
+    """A streamed scan and a served tick under ``jax.profiler``; the
+    engine's events on the trace's ``/host:CPU`` plane, per thread, as
+    (name, start_ns, end_ns)."""
+    import jax
+    from jax.profiler import ProfileData
+    from repro.serve.forest import ForestServeEngine
+    engine, forest, x = env
+    serve = ForestServeEngine(buckets=(8,), algorithm=FUSED)
+    serve.register_model("m", forest)
+    engine.infer("dense@host", forest, algorithm=FUSED, batch_pages=2)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        if armed:
+            TRACER.enable()
+        try:
+            engine.infer("dense@host", forest, algorithm=FUSED,
+                         batch_pages=2)
+            reqs = [serve.submit("m", x[i]) for i in range(3)]
+            serve.drain()
+            for r in reqs:
+                r.wait(5.0)
+        finally:
+            TRACER.disable()
+    serve.unregister_model("m")
+    path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    host, = [p for p in ProfileData.from_file(path).planes
+             if p.name == "/host:CPU"]
+    return [[(e.name, e.start_ns, e.start_ns + e.duration_ns)
+             for e in line.events if _engine_name(e.name)]
+            for line in host.lines]
+
+
+def _innermost_parent(event, line):
+    name, s, e = event
+    around = [o for o in line if o is not event and o[1] <= s and e <= o[2]]
+    return min(around, key=lambda o: o[2] - o[1])[0] if around else None
+
+
+def test_armed_spans_land_on_the_profiler_host_plane(env, tmp_path):
+    lines = _profiled(env, tmp_path, armed=True)
+    events = [ev for line in lines for ev in line]
+    names = {n for n, _, _ in events}
+    assert set(PHASE_PARENTS) | {"serve.tick", "scan.execute"} <= names
+    # the catalogue name alone: TraceMe folds attrs into the name as #k=v#
+    assert not any("#" in n for n in names)
+    for line in lines:
+        for ev in line:
+            if ev[0] in PHASE_PARENTS:
+                assert _innermost_parent(ev, line) == PHASE_PARENTS[ev[0]], ev
+
+
+def test_disarmed_tracer_leaves_no_annotation(env, tmp_path):
+    lines = _profiled(env, tmp_path, armed=False)
+    assert [ev for line in lines for ev in line] == []
+
+
+def test_obs_imports_and_traces_without_jax():
+    """``repro.obs`` is stdlib-only: with JAX unimportable it imports,
+    arms and records spans, which then simply do not annotate."""
+    code = (
+        "import sys; sys.modules['jax'] = None\n"
+        "from repro.obs import TRACER\n"
+        "TRACER.enable()\n"
+        "with TRACER.span('query.infer'):\n"
+        "    pass\n"
+        "assert TRACER._annotate is None\n"
+        "assert [s.name for s in TRACER.finished()] == ['query.infer']\n")
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env = dict(os.environ, PYTHONPATH=src)
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr

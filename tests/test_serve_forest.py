@@ -146,6 +146,25 @@ def test_batch_tier_waits_for_deadline():
     assert all(r.done.is_set() for r in reqs)
 
 
+def test_each_flush_stamps_its_own_admission_time():
+    """A tick loop under a backlog flushes several batches: each request
+    is admitted when its own flush takes it, so the second flush of one
+    ``tick()`` stamps a later ``admitted_at`` than the first, after the
+    first flush's answers were handed back."""
+    eng = ForestServeEngine(buckets=(8,))
+    eng.register_model("m", _forest(5))
+    reqs = [eng.submit("m", _rows(40 + i, 1), priority=TIER_BATCH)
+            for i in range(16)]
+    now = time.perf_counter()
+    assert eng.tick(now=now) == 16                 # two full buckets
+    first, second = reqs[:8], reqs[8:]             # FIFO for batch tier
+    assert len({r.admitted_at for r in first}) == 1
+    assert len({r.admitted_at for r in second}) == 1
+    assert now <= first[0].admitted_at <= first[0].finished_at
+    assert second[0].admitted_at > first[0].admitted_at
+    assert second[0].admitted_at >= first[0].finished_at
+
+
 def test_admission_timeout_sheds_to_batch_tier():
     """PR 6 degradation ladder, coalescer edition: an interactive
     request queued past its timeout is demoted to the batch tier
