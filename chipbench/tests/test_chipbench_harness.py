@@ -36,19 +36,8 @@ def test_run_prints_the_contract_line(tiny, cell):
 def test_traced_run_reports_per_layer_metrics(tiny, cell):
     res = run_tiny(cell, traced=True)
     assert res["correct"] is True
-    layer = spec.cell_metrics(spec.load_benchmark(), cell, True)
-    per_layer = _names(layer)
+    per_layer = _names(spec.cell_metrics(spec.load_benchmark(), cell, True))
     assert res["metrics"] and set(res["metrics"]) <= set(per_layer)
-    # what the engine itself records is read off the chip too
-    program = {m["name"] for m in layer
-               if m["source"] in ("program_span", "program_counter")}
-    assert program <= set(res["metrics"])
-    if "serve_tick_ms" in program:
-        parts = sum(res["metrics"][m]["value"] for m in (
-            "serve_upload_ms", "serve_dispatch_ms", "serve_result_wait_ms",
-            "serve_tick_self_ms"))
-        assert parts == pytest.approx(res["metrics"]["serve_tick_ms"]
-                                      ["value"], rel=0.02)
     # off the chip the trace holds no TPU plane: no device metric, no
     # breakdown, no busy time (a reader with nothing to read is silent)
     assert "breakdown" not in res and "busy_s" not in res["device"]

@@ -1,6 +1,7 @@
 """The readers of the engine's phase spans, fed made-up span lists: each
 value, the tick's four parts adding up to ``serve_tick_ms``, and silence
-where the program records no such span."""
+where the program records no such span; then the same metrics read from
+traced runs of the tiny cells."""
 
 import dataclasses
 import itertools
@@ -8,6 +9,7 @@ import itertools
 import pytest
 
 from chipbench import spec
+from chipbench.tests.conftest import CELLS, run_tiny
 
 _ids = itertools.count(1)
 
@@ -96,3 +98,21 @@ def test_silent_without_the_phase_spans(metric):
 
 def test_padding_silent_without_ticks():
     assert read("serve_padding_pct", []) is None
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_traced_tiny_cell_reports_its_program_metrics(tiny, cell):
+    """Every metric the engine itself records is in a traced line of the
+    cells that list it, and a served tick's four parts add up to it."""
+    res = run_tiny(cell, traced=True)
+    assert res["correct"] is True
+    program = {m["name"]
+               for m in spec.cell_metrics(spec.load_benchmark(), cell, True)
+               if m["source"] in ("program_span", "program_counter")}
+    assert program <= set(res["metrics"])
+    if "serve_tick_ms" in program:
+        parts = sum(res["metrics"][m]["value"] for m in (
+            "serve_upload_ms", "serve_dispatch_ms", "serve_result_wait_ms",
+            "serve_tick_self_ms"))
+        assert parts == pytest.approx(res["metrics"]["serve_tick_ms"]
+                                      ["value"], rel=0.02)
