@@ -93,6 +93,10 @@ METRIC_NAMES = (
     "scan.batch_resubmits",
     "scan.degraded_to_sync",
     "scan.deadline_hits",
+    # fused kernels: traces of each predicated walk
+    # (kernels/forest_predicated.py)
+    "kernel.predicated.node_major_traces",
+    "kernel.predicated.row_major_traces",
     # store / loader (db/store.py, db/loader.py)
     "store.puts",
     "store.moves",

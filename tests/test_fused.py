@@ -17,8 +17,11 @@ from repro.core.forest import make_forest, pad_trees
 from repro.core.postprocess import aggregate_raw, postprocess, predict_proba
 from repro.kernels.ops import (FUSED_KERNEL_ALGORITHMS, KERNEL_ALGORITHMS,
                                predict_sum_pallas)
+from repro.kernels.ref import ref_predicated
+from repro.obs import METRICS, METRIC_NAMES
 
-from conftest import random_forest_arrays
+from conftest import (NODE_MAJOR_GRID, NODE_MAJOR_IDS, node_major_case,
+                      random_forest_arrays)
 
 BASES = ("predicated", "hummingbird", "quickscorer")
 
@@ -159,3 +162,42 @@ def test_fused_default_blocks_and_padding(rng, base):
         forest, x, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("case", NODE_MAJOR_GRID, ids=NODE_MAJOR_IDS)
+def test_fused_predicated_node_major_walk(case):
+    """128-row tiles walk node-major; the fused sums are the row-major
+    walk's (``block_b=64``) bit for bit and match the reference."""
+    forest, x, kw = node_major_case(*case, seed=sum(case[:4]) + 1)
+    fused = FUSED_KERNEL_ALGORITHMS["predicated_pallas_fused"]
+    node_major = fused(forest, x, block_b=128, block_t=4, interpret=True,
+                       **kw)
+    row_major = fused(forest, x, block_b=64, block_t=4, interpret=True,
+                      **kw)
+    assert np.array_equal(np.asarray(node_major), np.asarray(row_major))
+    want = aggregate_raw(ref_predicated(forest, x))
+    np.testing.assert_allclose(np.asarray(node_major), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+WALKS = ("kernel.predicated.node_major_traces",
+         "kernel.predicated.row_major_traces")
+
+
+def test_walk_counters_follow_the_row_tile():
+    """Default blocks: a 1,024-row scan batch traces the node-major walk;
+    the serve plane's 8- and 32-row buckets trace only the row-major one."""
+    assert set(WALKS) <= set(METRIC_NAMES)
+    forest, x, _ = node_major_case(1024, 5, 8, 28, None, 0.1, seed=3)
+
+    def traced(B):
+        before = [METRICS.counter(n).value for n in WALKS]
+        jax.block_until_ready(FUSED_KERNEL_ALGORITHMS[
+            "predicated_pallas_fused"](forest, x[:B], interpret=True))
+        return [METRICS.counter(n).value - b for n, b in zip(WALKS, before)]
+
+    node_major, row_major = traced(1024)
+    assert node_major > 0 and row_major == 0
+    for bucket in (8, 32):
+        node_major, row_major = traced(bucket)
+        assert node_major == 0 and row_major > 0

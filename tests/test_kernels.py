@@ -14,7 +14,8 @@ from repro.core.forest import make_forest
 from repro.kernels.ops import KERNEL_ALGORITHMS, predict_raw_pallas
 from repro.kernels.ref import REFERENCES
 
-from conftest import random_forest_arrays
+from conftest import (NODE_MAJOR_GRID, NODE_MAJOR_IDS, node_major_case,
+                      random_forest_arrays)
 
 KERNELS = sorted(KERNEL_ALGORITHMS)
 
@@ -71,6 +72,23 @@ def test_kernel_dtype_sweep(rng, dtype):
                              "predicated_pallas", block_b=8, block_t=2,
                              interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("case", NODE_MAJOR_GRID, ids=NODE_MAJOR_IDS)
+def test_predicated_node_major_walk(case):
+    """Per-tree scores of the node-major walk are the row-major walk's
+    bit for bit, and match the jnp reference."""
+    forest, x, kw = node_major_case(*case, seed=sum(case[:4]))
+    node_major = predict_raw_pallas(forest, x, "predicated_pallas",
+                                    block_b=128, block_t=4, interpret=True,
+                                    **kw)
+    row_major = predict_raw_pallas(forest, x, "predicated_pallas",
+                                   block_b=64, block_t=4, interpret=True,
+                                   **kw)
+    assert np.array_equal(np.asarray(node_major), np.asarray(row_major))
+    want = REFERENCES["predicated_pallas"](forest, x)
+    np.testing.assert_allclose(np.asarray(node_major), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 
 
