@@ -11,17 +11,19 @@ module is the scan loop that pages those blocks through device memory.
 ``StreamingScanExecutor`` replaces the hand-rolled per-batch loop that
 used to live inside ``ForestQueryEngine.infer``: every plan (udf / rel),
 every storage format (dense rows / CSR pages), and every tier (device /
-host / disk) runs the SAME loop.  Sources implement the ``ScanSource``
-protocol (``page_slice`` + ``to_device``), so nothing downstream ever
-branches on where the pages live — a disk-tier source's ``page_slice``
-is an ``np.memmap`` view, so its DMA reads straight off the file.
+pinned / host / disk) runs the SAME loop.  Sources implement the
+``ScanSource`` protocol (``page_slice`` + ``to_device``), so nothing
+downstream ever branches on where the pages live — a disk-tier source's
+``page_slice`` is an ``np.memmap`` view, so its DMA reads straight off
+the file.
 
 The loop is a double-buffered DMA pipeline (``prefetch_depth=2``) with a
 TRULY asynchronous drain:
 
-    batch i+1   pages in flight via async ``jax.device_put`` honoring the
-                store's ``data_sharding`` (host/disk tiers; a no-op view
-                on the device tier) — issued by the compute thread
+    batch i+1   pages in flight: a copy out of page-locked host memory
+                (pinned tier), an async ``jax.device_put`` honoring the
+                store's ``data_sharding`` (host/disk tiers), or a no-op
+                view (device tier) — issued by the compute thread
     batch i     runs its (shard_map-wrapped or mesh-less) fused kernel
                 stages on the compute thread
     batch i-1   predictions drain on a DEDICATED DRAIN WORKER THREAD:
@@ -138,7 +140,7 @@ class ScanSource(Protocol):
     """
 
     name: str
-    tier: str                        # "device" | "host" | "disk"
+    tier: str                        # "device" | "pinned" | "host" | "disk"
     num_rows: int                    # true N (pre-padding)
 
     @property
@@ -149,14 +151,20 @@ class ScanSource(Protocol):
 
     def page_slice(self, first_page: int, num_pages: int) -> Any:
         """Contiguous page range in the source's OWN tier (device view,
-        host numpy view, or disk mmap view — views, not copies, on every
-        tier; a disk view faults in only the pages the batch touches)."""
+        ``store.PinnedSpan``, host numpy view, or disk mmap view — views,
+        not copies, on every tier; a disk view faults in only the pages
+        the batch touches)."""
         ...
 
     def to_device(self, block: Any, sharding: Any = None) -> Any:
-        """Stage a block onto device(s).  Host/disk tiers: an (async)
-        ``jax.device_put`` honoring ``sharding``; device tier: identity
-        (the no-op transfer stage)."""
+        """Stage a block onto device(s).  Pinned tier: a batch that is one
+        of the page-locked table's groups (every batch of the default
+        plan) is one ``jax.device_put`` of the group, already in the
+        device's layout, which the DMA engines run beside the kernel (no
+        host-side relayout, no staging copy on the host CPU); any other
+        span is cut from its groups on the device; host/disk tiers: an (async) ``jax.device_put``
+        honoring ``sharding``, which relays the numpy rows out on the
+        host CPU; device tier: identity (the no-op transfer stage)."""
         ...
 
 
@@ -174,6 +182,9 @@ class ScanStats:
     prefetch_depth: int              # 1 = synchronous, 2 = double-buffered
     max_in_flight: int = 0           # peak live device page buffers (<= 2)
     bytes_streamed: int = 0          # off-device->device bytes shipped
+    pinned_bytes: int = 0            # of which copied from pinned memory
+    host_relayout_bytes: int = 0     # of which shipped from numpy pages
+    #                                  (host/disk), relaid out on the host
     transfer_issue_s: float = 0.0    # time spent ISSUING device_puts
     transfer_wait_s: float = 0.0     # EXPOSED wait for pages to be ready
     #                                  (what double-buffering hides)
@@ -643,7 +654,12 @@ class StreamingScanExecutor:
             stats.transfer_issue_s += time.perf_counter() - t0
             pending.popleft()
             if source.tier != "device":
-                stats.bytes_streamed += _block_nbytes(block)
+                nbytes = _block_nbytes(block)
+                stats.bytes_streamed += nbytes
+                if source.tier == "pinned":
+                    stats.pinned_bytes += nbytes
+                else:
+                    stats.host_relayout_bytes += nbytes
             live += 1
             stats.max_in_flight = max(stats.max_in_flight, live)
             assert live <= MAX_IN_FLIGHT, \
@@ -793,6 +809,9 @@ class StreamingScanExecutor:
                 METRICS.counter("scan.batches").inc(stats.batches)
                 METRICS.counter("scan.bytes_streamed").inc(
                     stats.bytes_streamed)
+                METRICS.counter("scan.pinned_bytes").inc(stats.pinned_bytes)
+                METRICS.counter("scan.host_relayout_bytes").inc(
+                    stats.host_relayout_bytes)
                 METRICS.counter("scan.retries").inc(stats.retries)
                 METRICS.counter("scan.batch_resubmits").inc(
                     stats.batch_resubmits)

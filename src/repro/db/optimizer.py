@@ -243,7 +243,7 @@ class CostBasedOptimizer:
         # byte through host→device DMA once per query (disk additionally
         # pays the file read, modeled at half the DMA rate)
         h2d = peaks.get("h2d_bandwidth", peaks["hbm_bandwidth"])
-        if cell.tier == "host":
+        if cell.tier in ("pinned", "host"):
             cost += data_nbytes / h2d
         elif cell.tier == "disk":
             cost += data_nbytes / h2d + data_nbytes / (h2d / 2.0)

@@ -25,11 +25,19 @@ LADDER (see ``docs/architecture.md`` for the full design):
 
   ``device``  the original layout — device-resident jax arrays, consumed
               by kernels with zero staging (dataset size capped by HBM);
+  ``pinned``  page-locked host memory in the chip's own layout: page
+              groups of one stream batch each, jax arrays in the
+              device's ``pinned_host`` memory kind, relaid out once at
+              ingest (``store.pin``).  Its pages stay in host RAM, and a
+              default-plan scan batch reaches the device as one
+              memory-space copy of its group, beside the kernel and with
+              no host-side relayout (``PinnedTable``) — dense rows only;
   ``host``    page-aligned host numpy blocks — the in-RAM out-of-core
               tier.  The streaming scan executor (``db/executor.py``)
               pages a host dataset through device memory batch by batch,
               double buffered, so datasets far larger than device memory
-              execute;
+              execute; each batch is relaid out into the device's layout
+              on the host CPU as it is shipped;
   ``disk``    page-aligned memory-mapped files under the store's
               ``spill_dir`` — the bottom rung.  Dense rows are one mmap
               file; a CSR dataset is three (indptr / indices / values
@@ -42,22 +50,25 @@ LADDER (see ``docs/architecture.md`` for the full design):
               residency, not ingest residency.)
 
 ``put(..., tier=...)`` / ``put_sparse(..., tier=...)`` accept an explicit
-tier or ``"auto"``: the auto cascade walks the ladder top-down — an
+tier or ``"auto"``: the auto cascade walks device -> host -> disk — an
 ingest that would push the device-resident total past
 ``device_budget_bytes`` spills to host, and one that would also push the
-host-resident total past ``host_budget_bytes`` spills to disk.  Catalog
+host-resident total past ``host_budget_bytes`` spills to disk.  The
+``pinned`` rung is only ever asked for by name; its bytes are host RAM,
+so they count toward ``host_nbytes`` and the host budget.  Catalog
 entries carry the tier, and the store accounts ``nbytes`` PER TIER
-(``device_nbytes`` / ``host_nbytes`` / ``disk_nbytes``).  ``store.move``
-migrates a dataset between any two tiers preserving the page layout
-exactly; ``store.drop`` deletes the spill files the store created.  Both
-dataset classes implement the executor's ``ScanSource`` protocol
-(``page_slice`` in their own tier + ``to_device`` staging), so no caller
-ever branches on where pages live.
+(``device_nbytes`` / ``pinned_nbytes`` / ``host_nbytes`` /
+``disk_nbytes``).  ``store.move`` migrates a dataset between any two
+tiers preserving the page layout exactly; ``store.drop`` deletes the
+spill files the store created.  Both dataset classes implement the
+executor's ``ScanSource`` protocol (``page_slice`` in their own tier +
+``to_device`` staging), so no caller ever branches on where pages live.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import os
 import re
@@ -69,24 +80,106 @@ from typing import Any, Callable, Iterator
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
+from repro.db.executor import DEFAULT_STREAM_BATCH_BYTES
 from repro.db.faults import (FaultInjector, InjectedFault, RetryPolicy,
                              ScanFault)
 from repro.db.sparse import CSRPages, csr_from_dense, paginate_csr
 from repro.obs import METRICS, TRACER
 
 __all__ = ["StoredDataset", "SparseStoredDataset", "TensorBlockStore",
-           "DenseStreamWriter", "mmap_array", "TIERS"]
+           "DenseStreamWriter", "PinnedSpan", "PinnedTable", "mmap_array", "TIERS"]
 
-#: the tier ladder, fastest first — the ``auto`` cascade walks it top-down
-TIERS = ("device", "host", "disk")
+#: the tier ladder, fastest first.  The ``auto`` cascade walks it
+#: top-down but skips ``pinned``, which an ingest names explicitly.
+TIERS = ("device", "pinned", "host", "disk")
+
+#: memory kind of the ``pinned`` tier's pages
+PINNED = "pinned_host"
 
 
 def _check_tier(tier: str) -> str:
     if tier not in TIERS:
         raise ValueError(f"unknown tier {tier!r}; expected one of {TIERS}")
     return tier
+
+
+def _dense_only(tier: str) -> str:
+    """The tiers a CSR dataset may live on: all but ``pinned``."""
+    if _check_tier(tier) == "pinned":
+        raise ValueError("the pinned tier holds dense rows only; store a "
+                         "CSR dataset on the device, host or disk tier")
+    return tier
+
+
+@functools.partial(jax.jit, static_argnames="rows")
+def _pinned_take(data, lo, *, rows):
+    """Rows ``[lo, lo + rows)`` of a pinned group, copied to device memory
+    by one program (a DMA on the device's own stream, so it runs between
+    kernels, not beside them).  ``lo`` is traced, so every piece of one
+    size shares one executable."""
+    return jax.device_put(jax.lax.dynamic_slice_in_dim(data, lo, rows, 0),
+                          jax.memory.Space.Device)
+
+
+@dataclasses.dataclass(frozen=True)
+class PinnedTable:
+    """The ``pinned`` tier's ``[N_padded, F]`` rows: consecutive page
+    groups, each one jax array in the ``pinned_host`` memory kind, laid
+    out in the device's own layout.  A group is one stream batch of the
+    engine's default plan, so such a batch reaches the device as one
+    ``jax.device_put`` of its group: a copy the runtime's DMA engines
+    run beside the kernel, with no host relayout and no host-to-device
+    argument.  Any other page range is cut out of its groups on the
+    device's stream (``_pinned_take``) and joined there."""
+
+    groups: tuple[jax.Array, ...]  # [group_rows, F] each; the last may
+    #                                be shorter
+    group_rows: int
+
+    @property
+    def shape(self) -> tuple[int, int]:
+        return (sum(g.shape[0] for g in self.groups),
+                self.groups[0].shape[1])
+
+    @property
+    def dtype(self):
+        return self.groups[0].dtype
+
+    @property
+    def size(self) -> int:
+        rows, cols = self.shape
+        return rows * cols
+
+    def __array__(self, dtype=None, copy=None) -> np.ndarray:
+        out = np.concatenate([np.asarray(g) for g in self.groups])
+        return out if dtype is None else out.astype(dtype)
+
+    def take(self, lo: int, rows: int) -> jax.Array:
+        """Rows ``[lo, lo + rows)`` in device memory."""
+        pieces = []
+        for g in range(lo // self.group_rows,
+                       -(-(lo + rows) // self.group_rows)):
+            group, start = self.groups[g], g * self.group_rows
+            a, b = max(lo, start), min(lo + rows, start + group.shape[0])
+            if b - a == group.shape[0]:
+                pieces.append(jax.device_put(
+                    group, group.sharding.with_memory_kind("device")))
+            else:
+                pieces.append(_pinned_take(group, a - start, rows=b - a))
+        return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces)
+
+
+@dataclasses.dataclass(frozen=True)
+class PinnedSpan:
+    """A page range of a ``pinned`` table: where the rows lie, not a
+    copy.  ``StoredDataset.to_device`` copies it to device memory."""
+
+    table: PinnedTable
+    lo: int                       # first row
+    rows: int
 
 
 def _host_copy(a) -> np.ndarray:
@@ -120,7 +213,8 @@ def mmap_array(path: str, arr: np.ndarray) -> np.memmap:
 class StoredDataset:
     name: str
     data: Any                     # [N_padded, F]: jax.Array (device tier,
-    #                               row-sharded), np.ndarray (host tier,
+    #                               row-sharded), PinnedTable (pinned
+    #                               tier), np.ndarray (host tier,
     #                               page-aligned pages), or np.memmap
     #                               (disk tier, page-aligned mmap file)
     num_rows: int                 # true N (pre-padding)
@@ -150,22 +244,31 @@ class StoredDataset:
 
     def page_slice(self, first_page: int, num_pages: int):
         """[num_pages * page_rows, F] contiguous page range, a VIEW in the
-        dataset's own tier (device slice / host numpy view / np.memmap
-        view — a disk-tier slice stays lazy: only the OS pages the batch
-        touches are faulted in, never the whole file)."""
+        dataset's own tier (device slice / ``PinnedSpan`` / host numpy
+        view / np.memmap view — a disk-tier slice stays lazy: only the OS
+        pages the batch touches are faulted in, never the whole file)."""
         lo = first_page * self.page_rows
-        if self.tier != "device":
-            return self.data[lo: lo + num_pages * self.page_rows]
-        return jax.lax.dynamic_slice_in_dim(
-            self.data, lo, num_pages * self.page_rows, axis=0)
+        rows = num_pages * self.page_rows
+        if self.tier == "device":
+            return jax.lax.dynamic_slice_in_dim(self.data, lo, rows, axis=0)
+        if self.tier == "pinned":
+            return PinnedSpan(self.data, lo, rows)
+        return self.data[lo: lo + rows]
 
     def to_device(self, block, sharding=None):
-        """ScanSource staging: host/disk tiers issue an (async) device_put
-        honoring the store's data sharding (a disk-tier mmap view is read
+        """ScanSource staging: the pinned tier copies its span from pinned
+        host memory to device memory (``scan.page_copy``,
+        ``PinnedTable.take``; the rows keep the table's sharding);
+        host/disk tiers issue an (async)
+        device_put honoring the store's data sharding, which relays the
+        numpy rows out on the host CPU (a disk-tier mmap view is read
         straight into the transfer — no intermediate host copy of the
         whole dataset ever exists); device tier is a no-op."""
         if self.tier == "device":
             return block
+        if self.tier == "pinned":
+            with TRACER.span("scan.page_copy", rows=block.rows):
+                return block.table.take(block.lo, block.rows)
         return jax.device_put(block, sharding)
 
     def batches(self, pages_per_batch: int) -> Iterator[tuple[int, Any]]:
@@ -356,6 +459,28 @@ class TensorBlockStore:
             return int(self.mesh.shape["data"])
         return 1
 
+    def _pin(self, arr: np.ndarray, page_rows: int) -> PinnedTable:
+        """Lay page-aligned ``arr`` out in page-locked host memory in the
+        device's layout: the pinned tier's one relayout, at ingest.  The
+        groups are the engine's default off-device batch (half the device
+        budget, else ``DEFAULT_STREAM_BATCH_BYTES``, in data-axis units;
+        ``ForestQueryEngine.default_batch_pages``), so each such batch is
+        one group.  On a mesh each group keeps the data sharding, in the
+        ``pinned_host`` memory kind; off it, groups lie in the first
+        device's ``pinned_host`` memory."""
+        sharding = self.data_sharding()
+        sharding = (SingleDeviceSharding(jax.devices()[0], memory_kind=PINNED)
+                    if sharding is None else sharding.with_memory_kind(PINNED))
+        unit = self.data_axis_size
+        target = (self.device_budget_bytes // 2 if self.device_budget_bytes
+                  else DEFAULT_STREAM_BATCH_BYTES)
+        fit = target // max(page_rows * arr[:1].nbytes, 1)
+        group_rows = max(unit, fit // unit * unit) * page_rows
+        with TRACER.span("store.pin", bytes=int(arr.nbytes)):
+            groups = tuple(jax.device_put(arr[lo: lo + group_rows], sharding)
+                           for lo in range(0, len(arr), group_rows))
+            return PinnedTable(jax.block_until_ready(groups), group_rows)
+
     def data_sharding(self) -> NamedSharding | None:
         """Row/page sharding for stored blocks: dim 0 over ``data``,
         replicated over ``model`` (None off-mesh).  One definition for
@@ -372,9 +497,15 @@ class TensorBlockStore:
                    if d.tier == "device")
 
     @property
-    def host_nbytes(self) -> int:
+    def pinned_nbytes(self) -> int:
         return sum(d.nbytes for d in self._datasets.values()
-                   if d.tier == "host")
+                   if d.tier == "pinned")
+
+    @property
+    def host_nbytes(self) -> int:
+        """Host-RAM dataset bytes: the host tier and the pinned tier."""
+        return sum(d.nbytes for d in self._datasets.values()
+                   if d.tier in ("host", "pinned"))
 
     @property
     def disk_nbytes(self) -> int:
@@ -423,7 +554,8 @@ class TensorBlockStore:
     ) -> StoredDataset:
         """Ingest [N, F] rows: pad to whole pages (NaN rows — never counted
         in results), resolve the tier, lay out (device: shard rows over the
-        mesh ``data`` axis; host: keep page-aligned numpy; disk: write one
+        mesh ``data`` axis; pinned: page-locked host memory in the
+        device's layout; host: keep page-aligned numpy; disk: write one
         page-aligned mmap file), register."""
         page_rows = page_rows or self.default_page_rows
         arr = np.asarray(jax.device_get(data))
@@ -440,6 +572,9 @@ class TensorBlockStore:
         self.drop_decisions(dataset=name)  # re-put: old decisions are stale
         if tier == "host":
             stored = np.ascontiguousarray(arr, np_dtype)
+        elif tier == "pinned":
+            stored = self._pin(np.ascontiguousarray(arr, np_dtype),
+                               page_rows)
         elif tier == "disk":
             stored = self._disk_array(
                 name, "rows", np.ascontiguousarray(arr, np_dtype))
@@ -496,8 +631,12 @@ class TensorBlockStore:
             zeros kept unless ``drop_zeros``), converted here.
 
         Page padding mirrors ``put``: rows pad to whole pages as EMPTY
-        rows, and the page count pads to the mesh ``data`` axis.
+        rows, and the page count pads to the mesh ``data`` axis.  The
+        ``pinned`` tier holds dense rows only: asking for it raises
+        ``ValueError``.
         """
+        if tier != "auto":
+            _dense_only(tier)
         page_rows = page_rows or self.default_page_rows
         pages_multiple = self.data_axis_size
         self._release_disk(name)          # re-put: old spill files go away
@@ -641,10 +780,12 @@ class TensorBlockStore:
 
     def _move_impl(self, name: str, tier: str):
         """Migrate a dataset between any two tiers of the ladder
-        (eviction: device -> host -> disk; promotion: the reverse).  Page
-        layout is preserved exactly, so the page↔batch mapping — and
-        therefore every prediction — is unchanged; compiled plans stay
-        valid (tier is a runtime property of the scan, not of the plan).
+        (eviction: device -> pinned -> host -> disk; promotion: the
+        reverse; a CSR dataset skips ``pinned``, which holds dense rows
+        only).  Page layout is preserved exactly, so the page↔batch
+        mapping — and therefore every prediction — is unchanged; compiled
+        plans stay valid (tier is a runtime property of the scan, not of
+        the plan).
         Moving OFF the disk tier deletes the spill files this store wrote
         (after the copy — live views keep the unlinked inodes alive).
 
@@ -656,8 +797,11 @@ class TensorBlockStore:
         it the per-tier ``*_nbytes`` accounting) is untouched — then a
         structured ``ScanFault`` is raised.  A failed move never leaks
         orphaned page files and never corrupts tier accounting."""
-        _check_tier(tier)
         ds = self.get(name)
+        if ds.storage_format == "csr":
+            _dense_only(tier)
+        else:
+            _check_tier(tier)
         if ds.tier == tier:
             return ds
         was_disk = ds.tier == "disk"
@@ -680,6 +824,8 @@ class TensorBlockStore:
             src = read_source(arr)
             if tier == "host":
                 return src
+            if tier == "pinned":
+                return self._pin(src, ds.page_rows)
             if tier == "disk":
                 return self._disk_array(name, label, src)
             out = jnp.asarray(src)
@@ -958,6 +1104,8 @@ class DenseStreamWriter:
                 stored = self._buf
             elif self.tier == "host":
                 stored = self._buf
+            elif self.tier == "pinned":
+                stored = store._pin(self._buf, self.page_rows)
             else:
                 stored = jnp.asarray(self._buf)
                 sharding = store.data_sharding()

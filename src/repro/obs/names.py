@@ -31,6 +31,7 @@ SPAN_NAMES = (
     "scan.batch",
     "scan.disk_read",
     "scan.dma_in",
+    "scan.page_copy",
     "scan.transfer_wait",
     "scan.compute",
     "scan.drain_submit",
@@ -40,6 +41,7 @@ SPAN_NAMES = (
     "store.put",
     "store.put_sparse",
     "store.move",
+    "store.pin",
     # external loaders (db/loader.py)
     "load.parse",
     "load.convert",
@@ -88,6 +90,8 @@ METRIC_NAMES = (
     # streaming scan rollups (db/executor.py)
     "scan.batches",
     "scan.bytes_streamed",
+    "scan.pinned_bytes",
+    "scan.host_relayout_bytes",
     "scan.retries",
     "scan.faults_injected",
     "scan.batch_resubmits",
