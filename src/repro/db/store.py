@@ -83,14 +83,15 @@ import numpy as np
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
-from repro.db.executor import DEFAULT_STREAM_BATCH_BYTES
+from repro.db.executor import stream_batch_pages
 from repro.db.faults import (FaultInjector, InjectedFault, RetryPolicy,
                              ScanFault)
 from repro.db.sparse import CSRPages, csr_from_dense, paginate_csr
 from repro.obs import METRICS, TRACER
 
 __all__ = ["StoredDataset", "SparseStoredDataset", "TensorBlockStore",
-           "DenseStreamWriter", "PinnedSpan", "PinnedTable", "mmap_array", "TIERS"]
+           "DenseStreamWriter", "PinnedSpan", "PinnedTable", "mmap_array",
+           "TIERS"]
 
 #: the tier ladder, fastest first.  The ``auto`` cascade walks it
 #: top-down but skips ``pinned``, which an ingest names explicitly.
@@ -462,20 +463,17 @@ class TensorBlockStore:
     def _pin(self, arr: np.ndarray, page_rows: int) -> PinnedTable:
         """Lay page-aligned ``arr`` out in page-locked host memory in the
         device's layout: the pinned tier's one relayout, at ingest.  The
-        groups are the engine's default off-device batch (half the device
-        budget, else ``DEFAULT_STREAM_BATCH_BYTES``, in data-axis units;
-        ``ForestQueryEngine.default_batch_pages``), so each such batch is
-        one group.  On a mesh each group keeps the data sharding, in the
+        groups are the engine's default off-device batch
+        (``executor.stream_batch_pages``), so each such batch is one
+        group.  On a mesh each group keeps the data sharding, in the
         ``pinned_host`` memory kind; off it, groups lie in the first
         device's ``pinned_host`` memory."""
         sharding = self.data_sharding()
         sharding = (SingleDeviceSharding(jax.devices()[0], memory_kind=PINNED)
                     if sharding is None else sharding.with_memory_kind(PINNED))
-        unit = self.data_axis_size
-        target = (self.device_budget_bytes // 2 if self.device_budget_bytes
-                  else DEFAULT_STREAM_BATCH_BYTES)
-        fit = target // max(page_rows * arr[:1].nbytes, 1)
-        group_rows = max(unit, fit // unit * unit) * page_rows
+        group_rows = page_rows * stream_batch_pages(
+            page_rows * arr[:1].nbytes, self.device_budget_bytes,
+            self.data_axis_size)
         with TRACER.span("store.pin", bytes=int(arr.nbytes)):
             groups = tuple(jax.device_put(arr[lo: lo + group_rows], sharding)
                            for lo in range(0, len(arr), group_rows))
