@@ -170,14 +170,13 @@ class ScanSource(Protocol):
         ...
 
     def to_device(self, block: Any, sharding: Any = None) -> Any:
-        """Stage a block onto device(s).  Pinned tier: a batch that is one
-        of the page-locked table's groups (every batch of the default
-        plan) is one ``jax.device_put`` of the group, already in the
-        device's layout, which the DMA engines run beside the kernel (no
-        host-side relayout, no staging copy on the host CPU); any other
-        span is cut from its groups on the device.  Host/disk tiers: an
-        (async) ``jax.device_put`` honoring ``sharding``, which relays
-        the numpy rows out on the host CPU.  Device tier: identity (the
+        """Stage a block onto device(s).  Pinned tier: one jitted slice of
+        the page-locked table, already in the device's layout, into
+        device memory — a single DMA with no host-side relayout, no
+        staging copy on the host CPU and no host argument (the start row
+        is a cached device scalar).  Host/disk tiers: an (async)
+        ``jax.device_put`` honoring ``sharding``, which relays the numpy
+        rows out on the host CPU.  Device tier: identity (the
         no-op transfer stage)."""
         ...
 

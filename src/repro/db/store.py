@@ -25,13 +25,12 @@ LADDER (see ``docs/architecture.md`` for the full design):
 
   ``device``  the original layout — device-resident jax arrays, consumed
               by kernels with zero staging (dataset size capped by HBM);
-  ``pinned``  page-locked host memory in the chip's own layout: page
-              groups of one stream batch each, jax arrays in the
-              device's ``pinned_host`` memory kind, relaid out once at
-              ingest (``store.pin``).  Its pages stay in host RAM, and a
-              default-plan scan batch reaches the device as one
-              memory-space copy of its group, beside the kernel and with
-              no host-side relayout (``PinnedTable``) — dense rows only;
+  ``pinned``  page-locked host memory in the chip's own layout: one jax
+              array in the device's ``pinned_host`` memory kind, relaid
+              out once at ingest (``store.pin``).  Its pages stay in
+              host RAM, and a scan batch reaches the device as one
+              memory-space copy of its page range (``_pinned_take``),
+              with no host-side relayout — dense rows only;
   ``host``    page-aligned host numpy blocks — the in-RAM out-of-core
               tier.  The streaming scan executor (``db/executor.py``)
               pages a host dataset through device memory batch by batch,
@@ -83,15 +82,13 @@ import numpy as np
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
-from repro.db.executor import stream_batch_pages
 from repro.db.faults import (FaultInjector, InjectedFault, RetryPolicy,
                              ScanFault)
 from repro.db.sparse import CSRPages, csr_from_dense, paginate_csr
 from repro.obs import METRICS, TRACER
 
 __all__ = ["StoredDataset", "SparseStoredDataset", "TensorBlockStore",
-           "DenseStreamWriter", "PinnedSpan", "PinnedTable", "mmap_array",
-           "TIERS"]
+           "DenseStreamWriter", "PinnedSpan", "mmap_array", "TIERS"]
 
 #: the tier ladder, fastest first.  The ``auto`` cascade walks it
 #: top-down but skips ``pinned``, which an ingest names explicitly.
@@ -117,60 +114,25 @@ def _dense_only(tier: str) -> str:
 
 @functools.partial(jax.jit, static_argnames="rows")
 def _pinned_take(data, lo, *, rows):
-    """Rows ``[lo, lo + rows)`` of a pinned group, copied to device memory
-    by one program (a DMA on the device's own stream, so it runs between
-    kernels, not beside them).  ``lo`` is traced, so every piece of one
-    size shares one executable."""
+    """Rows ``[lo, lo + rows)`` of a pinned table, copied to device memory
+    by one program: a slice out of the ``pinned_host`` memory space,
+    which the compiler issues as one DMA.  ``lo`` is a traced device
+    scalar (``_row_start``), so every batch of one size shares one
+    executable and ships no host argument."""
     return jax.device_put(jax.lax.dynamic_slice_in_dim(data, lo, rows, 0),
                           jax.memory.Space.Device)
 
 
-@dataclasses.dataclass(frozen=True)
-class PinnedTable:
-    """The ``pinned`` tier's ``[N_padded, F]`` rows: consecutive page
-    groups, each one jax array in the ``pinned_host`` memory kind, laid
-    out in the device's own layout.  A group is one stream batch of the
-    engine's default plan, so such a batch reaches the device as one
-    ``jax.device_put`` of its group: a copy the runtime's DMA engines
-    run beside the kernel, with no host relayout and no host-to-device
-    argument.  Any other page range is cut out of its groups on the
-    device's stream (``_pinned_take``) and joined there."""
-
-    groups: tuple[jax.Array, ...]  # [group_rows, F] each; the last may
-    #                                be shorter
-    group_rows: int
-
-    @property
-    def shape(self) -> tuple[int, int]:
-        return (sum(g.shape[0] for g in self.groups),
-                self.groups[0].shape[1])
-
-    @property
-    def dtype(self):
-        return self.groups[0].dtype
-
-    @property
-    def size(self) -> int:
-        rows, cols = self.shape
-        return rows * cols
-
-    def __array__(self, dtype=None, copy=None) -> np.ndarray:
-        out = np.concatenate([np.asarray(g) for g in self.groups])
-        return out if dtype is None else out.astype(dtype)
-
-    def take(self, lo: int, rows: int) -> jax.Array:
-        """Rows ``[lo, lo + rows)`` in device memory."""
-        pieces = []
-        for g in range(lo // self.group_rows,
-                       -(-(lo + rows) // self.group_rows)):
-            group, start = self.groups[g], g * self.group_rows
-            a, b = max(lo, start), min(lo + rows, start + group.shape[0])
-            if b - a == group.shape[0]:
-                pieces.append(jax.device_put(
-                    group, group.sharding.with_memory_kind("device")))
-            else:
-                pieces.append(_pinned_take(group, a - start, rows=b - a))
-        return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces)
+@functools.lru_cache(maxsize=4096)
+def _row_start(lo: int, sharding) -> jax.Array:
+    """Row ``lo`` as an int32 scalar in device memory beside a pinned
+    table of ``sharding``.  Each start is put once and kept, so a scan's
+    page copies after its first carry no host-to-device transfer."""
+    if isinstance(sharding, NamedSharding):
+        where = NamedSharding(sharding.mesh, P())
+    else:
+        where = SingleDeviceSharding(next(iter(sharding.device_set)))
+    return jax.device_put(np.int32(lo), where)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -178,7 +140,7 @@ class PinnedSpan:
     """A page range of a ``pinned`` table: where the rows lie, not a
     copy.  ``StoredDataset.to_device`` copies it to device memory."""
 
-    table: PinnedTable
+    data: jax.Array               # the whole [N_padded, F] pinned table
     lo: int                       # first row
     rows: int
 
@@ -214,8 +176,9 @@ def mmap_array(path: str, arr: np.ndarray) -> np.memmap:
 class StoredDataset:
     name: str
     data: Any                     # [N_padded, F]: jax.Array (device tier,
-    #                               row-sharded), PinnedTable (pinned
-    #                               tier), np.ndarray (host tier,
+    #                               row-sharded; in the pinned_host
+    #                               memory kind on the pinned tier),
+    #                               np.ndarray (host tier,
     #                               page-aligned pages), or np.memmap
     #                               (disk tier, page-aligned mmap file)
     num_rows: int                 # true N (pre-padding)
@@ -259,7 +222,7 @@ class StoredDataset:
     def to_device(self, block, sharding=None):
         """ScanSource staging: the pinned tier copies its span from pinned
         host memory to device memory (``scan.page_copy``,
-        ``PinnedTable.take``; the rows keep the table's sharding);
+        ``_pinned_take``; the rows keep the table's sharding);
         host/disk tiers issue an (async)
         device_put honoring the store's data sharding, which relays the
         numpy rows out on the host CPU (a disk-tier mmap view is read
@@ -269,7 +232,9 @@ class StoredDataset:
             return block
         if self.tier == "pinned":
             with TRACER.span("scan.page_copy", rows=block.rows):
-                return block.table.take(block.lo, block.rows)
+                return _pinned_take(
+                    block.data, _row_start(block.lo, block.data.sharding),
+                    rows=block.rows)
         return jax.device_put(block, sharding)
 
     def batches(self, pages_per_batch: int) -> Iterator[tuple[int, Any]]:
@@ -460,24 +425,16 @@ class TensorBlockStore:
             return int(self.mesh.shape["data"])
         return 1
 
-    def _pin(self, arr: np.ndarray, page_rows: int) -> PinnedTable:
+    def _pin(self, arr: np.ndarray) -> jax.Array:
         """Lay page-aligned ``arr`` out in page-locked host memory in the
-        device's layout: the pinned tier's one relayout, at ingest.  The
-        groups are the engine's default off-device batch
-        (``executor.stream_batch_pages``), so each such batch is one
-        group.  On a mesh each group keeps the data sharding, in the
-        ``pinned_host`` memory kind; off it, groups lie in the first
-        device's ``pinned_host`` memory."""
+        device's layout: the pinned tier's one relayout, at ingest.  On a
+        mesh the rows keep the data sharding, in the ``pinned_host``
+        memory kind; off it, they lie in the first device's."""
         sharding = self.data_sharding()
         sharding = (SingleDeviceSharding(jax.devices()[0], memory_kind=PINNED)
                     if sharding is None else sharding.with_memory_kind(PINNED))
-        group_rows = page_rows * stream_batch_pages(
-            page_rows * arr[:1].nbytes, self.device_budget_bytes,
-            self.data_axis_size)
         with TRACER.span("store.pin", bytes=int(arr.nbytes)):
-            groups = tuple(jax.device_put(arr[lo: lo + group_rows], sharding)
-                           for lo in range(0, len(arr), group_rows))
-            return PinnedTable(jax.block_until_ready(groups), group_rows)
+            return jax.block_until_ready(jax.device_put(arr, sharding))
 
     def data_sharding(self) -> NamedSharding | None:
         """Row/page sharding for stored blocks: dim 0 over ``data``,
@@ -571,8 +528,7 @@ class TensorBlockStore:
         if tier == "host":
             stored = np.ascontiguousarray(arr, np_dtype)
         elif tier == "pinned":
-            stored = self._pin(np.ascontiguousarray(arr, np_dtype),
-                               page_rows)
+            stored = self._pin(np.ascontiguousarray(arr, np_dtype))
         elif tier == "disk":
             stored = self._disk_array(
                 name, "rows", np.ascontiguousarray(arr, np_dtype))
@@ -823,7 +779,7 @@ class TensorBlockStore:
             if tier == "host":
                 return src
             if tier == "pinned":
-                return self._pin(src, ds.page_rows)
+                return self._pin(src)
             if tier == "disk":
                 return self._disk_array(name, label, src)
             out = jnp.asarray(src)
@@ -1103,7 +1059,7 @@ class DenseStreamWriter:
             elif self.tier == "host":
                 stored = self._buf
             elif self.tier == "pinned":
-                stored = store._pin(self._buf, self.page_rows)
+                stored = store._pin(self._buf)
             else:
                 stored = jnp.asarray(self._buf)
                 sharding = store.data_sharding()

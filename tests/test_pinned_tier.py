@@ -26,7 +26,7 @@ from repro.core.postprocess import aggregate_raw
 from repro.db.executor import ScanSource
 from repro.db.faults import FaultInjector, RetryPolicy
 from repro.db.query import ForestQueryEngine
-from repro.db.store import PinnedSpan, PinnedTable, TensorBlockStore
+from repro.db.store import PinnedSpan, TensorBlockStore
 from repro.kernels.ref import ref_predicated
 from repro.obs import METRICS, TRACER
 
@@ -57,10 +57,9 @@ def _engine(store):
 
 
 def _assert_pinned(ds):
-    """Every page group of ``ds`` lies in page-locked host memory."""
-    assert isinstance(ds.data, PinnedTable) and ds.data.groups
-    assert all(isinstance(g, jax.Array) and g.sharding.memory_kind ==
-               "pinned_host" for g in ds.data.groups)
+    """The rows of ``ds`` lie in page-locked host memory."""
+    assert isinstance(ds.data, jax.Array)
+    assert ds.data.sharding.memory_kind == "pinned_host"
 
 
 def _counters(fn):
@@ -202,18 +201,16 @@ def test_pinned_scan_is_bit_identical_to_the_device_tier(rows, batch_pages,
 
 
 @pytest.mark.parametrize("batch_pages", [None, 2, 4, 9])
-def test_pinned_groups_follow_the_stream_batch(batch_pages):
-    """Groups are the engine's default off-device batch (half the device
-    budget here: 3 pages), so a default-plan batch is one whole group;
-    any other batch is cut from its groups and joined, bit-identically."""
+def test_pinned_batches_of_any_size_match_the_device_tier(batch_pages):
+    """The default batch (half the device budget here: 3 pages) and any
+    other size are each one slice of the one pinned table, bit-identical
+    to the device-resident scan."""
     x = _rows(8 * PAGE + 5, seed=7)
     page_bytes = PAGE * F * 4
     store = TensorBlockStore(default_page_rows=PAGE,
                              device_budget_bytes=6 * page_bytes)
     store.put("dev", x, tier="device")
     ds = store.put("pin", x, tier="pinned")
-    assert ds.data.group_rows == 3 * PAGE
-    assert [g.shape[0] for g in ds.data.groups] == [3 * PAGE] * 3
     engine = _engine(store)
     forest = _forest(seed=7)
     kw = dict(algorithm=FUSED, batch_pages=batch_pages)
@@ -224,6 +221,22 @@ def test_pinned_groups_follow_the_stream_batch(batch_pages):
     assert rp.scan.pinned_bytes == ds.nbytes
     assert np.array_equal(np.asarray(rp.predictions),
                           np.asarray(rd.predictions))
+
+
+@pytest.mark.parametrize("first,num", [(0, 1), (2, 3), (5, 1)])
+def test_pinned_copy_ships_no_host_argument(first, num):
+    """Once a start row has been copied from, copying from it again moves
+    nothing from host to device: the start is a device scalar, and the
+    rows go pinned -> device inside one program."""
+    x = _rows(6 * PAGE, seed=8)
+    ds = TensorBlockStore(default_page_rows=PAGE).put("t", x, tier="pinned")
+    span = ds.page_slice(first, num)
+    ds.to_device(span)
+    with jax.transfer_guard("disallow"):
+        block = ds.to_device(span)
+    assert block.sharding.memory_kind == "device"
+    assert np.array_equal(np.asarray(block),
+                          x[first * PAGE: (first + num) * PAGE])
 
 
 def test_dma_halving_ladder_on_a_pinned_source():
@@ -297,10 +310,9 @@ NDEV = len(jax.devices())
 @pytest.mark.parametrize("plan", ["udf", "rel"])
 def test_mesh_pinned_scan_is_bit_identical_to_the_device_tier(plan,
                                                               batch_pages):
-    """On a (data x model) mesh each pinned group keeps the data sharding
-    in the pinned_host memory kind, and its batches (whole groups at 4
-    pages, cut across groups at 6) feed the shard_map plans
-    bit-identically to the device-resident run."""
+    """On a (data x model) mesh the pinned table keeps the data sharding
+    in the pinned_host memory kind, and its batches feed the shard_map
+    plans bit-identically to the device-resident run."""
     from jax.sharding import Mesh
     mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 4),
                 ("data", "model"))
@@ -310,11 +322,8 @@ def test_mesh_pinned_scan_is_bit_identical_to_the_device_tier(plan,
                              device_budget_bytes=8 * PAGE * F * 4)
     store.put("dev", x, tier="device")
     ds = store.put("pin", x, tier="pinned")
-    assert [g.shape[0] for g in ds.data.groups] == [4 * PAGE, 4 * PAGE,
-                                                   2 * PAGE]
     _assert_pinned(ds)
-    assert all(g.sharding.spec == jax.sharding.PartitionSpec("data", None)
-               for g in ds.data.groups)
+    assert ds.data.sharding.spec == jax.sharding.PartitionSpec("data", None)
     engine = _engine(store)
     kw = dict(algorithm=FUSED, plan=plan, batch_pages=batch_pages)
     rd = engine.infer("dev", forest, **kw)
