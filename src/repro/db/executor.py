@@ -107,7 +107,7 @@ from repro.db.operators import StageReport, run_stages
 from repro.obs import METRICS, TRACER
 
 __all__ = ["ScanSource", "ScanStats", "StreamingScanExecutor",
-           "MAX_IN_FLIGHT", "stream_batch_pages"]
+           "MAX_IN_FLIGHT"]
 
 #: hard ceiling on simultaneously live device page buffers: the one being
 #: computed on plus the one in DMA flight.  The executor asserts it.
@@ -119,19 +119,6 @@ MAX_IN_FLIGHT = 2
 #: query engine caps the default batch at this many bytes per in-flight
 #: buffer.
 DEFAULT_STREAM_BATCH_BYTES = 64 << 20
-
-
-def stream_batch_pages(page_nbytes: int, device_budget_bytes: int | None,
-                       unit: int) -> int:
-    """Pages in one default off-device scan batch: half the device budget,
-    so two in-flight page buffers together fit it, else
-    ``DEFAULT_STREAM_BATCH_BYTES``.  Sized in data-axis ``unit``s,
-    rounding DOWN (at least one unit), so the mesh divisibility round-up
-    cannot push the pair past the budget."""
-    target = (device_budget_bytes // 2 if device_budget_bytes
-              else DEFAULT_STREAM_BATCH_BYTES)
-    fit = target // max(page_nbytes, 1)
-    return max(unit, fit // unit * unit)
 
 #: how long one ``queue.put`` attempt blocks before the submit path
 #: re-checks drain-worker liveness.  The put still wakes IMMEDIATELY

@@ -98,8 +98,8 @@ from repro.core.forest import Forest, compact_forest, pad_trees, tree_slice
 from repro.core.reuse import (GLOBAL_CACHE, GLOBAL_PLAN_CACHE,
                               MaterializedModel, ModelReuseCache,
                               fingerprint_forest, mesh_signature)
-from repro.db.executor import (ScanStats, StreamingScanExecutor,
-                               stream_batch_pages)
+from repro.db.executor import (DEFAULT_STREAM_BATCH_BYTES, ScanStats,
+                               StreamingScanExecutor)
 from repro.db.faults import (Deadline, DegradedReport, FaultInjector,
                              RetryPolicy)
 from repro.db.operators import (Operator, StageReport, ndevices,
@@ -615,9 +615,11 @@ class ForestQueryEngine:
         the batch is capped at ``max_batch_pages``."""
         batch_pages = ds.num_pages
         if getattr(ds, "tier", "device") != "device":
-            batch_pages = min(ds.num_pages, stream_batch_pages(
-                ds.page_nbytes, self.store.device_budget_bytes,
-                max(1, self.fplan.n_data)))
+            budget = self.store.device_budget_bytes
+            target = budget // 2 if budget else DEFAULT_STREAM_BATCH_BYTES
+            unit = max(1, self.fplan.n_data)
+            fit = target // max(ds.page_nbytes, 1)
+            batch_pages = min(ds.num_pages, max(unit, fit // unit * unit))
         return min(batch_pages, self.max_batch_pages(ds, forest, algorithm))
 
     # ------------------------------------------------------------------

@@ -74,8 +74,8 @@ from repro.core.forest import Forest
 from repro.core.reuse import fingerprint_forest
 from repro.core.train import (TrainConfig, bin_features, edges_from_sample,
                               grow_forest_scanned, hist_update, route_level)
-from repro.db.executor import (ScanStats, StreamingScanExecutor,
-                               stream_batch_pages)
+from repro.db.executor import (DEFAULT_STREAM_BATCH_BYTES, ScanStats,
+                               StreamingScanExecutor)
 from repro.db.operators import Operator, split_into_stages
 from repro.kernels.gather import csr_block_to_dense, gather_inverse_map
 from repro.obs import METRICS, TRACER
@@ -124,9 +124,11 @@ def _auto_batch_pages(engine, ds) -> int:
     in data-axis units, rounded down; device tier scans whole."""
     if getattr(ds, "tier", "device") == "device":
         return ds.num_pages
-    return min(ds.num_pages, stream_batch_pages(
-        ds.page_nbytes, engine.store.device_budget_bytes,
-        max(1, engine.fplan.n_data)))
+    budget = engine.store.device_budget_bytes
+    target = budget // 2 if budget else DEFAULT_STREAM_BATCH_BYTES
+    unit = max(1, engine.fplan.n_data)
+    fit = target // max(ds.page_nbytes, 1)
+    return min(ds.num_pages, max(unit, fit // unit * unit))
 
 
 def _mesh_round(engine, ds, batch_pages: int) -> int:

@@ -187,11 +187,11 @@ def test_host_tier_without_budget_still_streams(data_and_forest,
     """An EXPLICIT host ingest on a budget-less store must not fall back
     to a whole-dataset device_put: the default batch is capped at the
     fixed streaming footprint instead."""
-    import repro.db.executor as ex
+    import repro.db.query as q
     x, xs, forest = data_and_forest
     store = TensorBlockStore(default_page_rows=PAGE)   # no budget
     ds = store.put("h", x, tier="host")
-    monkeypatch.setattr(ex, "DEFAULT_STREAM_BATCH_BYTES",
+    monkeypatch.setattr(q, "DEFAULT_STREAM_BATCH_BYTES",
                         3 * ds.page_nbytes)
     engine = _engine(store)
     res = engine.infer("h", forest, algorithm=FUSED, plan="udf")
